@@ -7,7 +7,10 @@ continuous-batching engine, print per-request generations.
 import argparse
 import time
 
+import jax
+
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import Request, ServingEngine
 
 
@@ -18,6 +21,7 @@ def main() -> None:
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--max-new-tokens", type=int, default=8)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch).smoke()
     eng = ServingEngine(cfg, max_batch=args.max_batch, max_len=64,
@@ -36,7 +40,9 @@ def main() -> None:
     for r in reqs[:5]:
         print(f"req {r.rid}: {r.out_tokens}")
     toks = stats["decode_steps"] * args.max_batch
-    print(f"~{toks / dt:.1f} batched tokens/s on CPU (smoke config)")
+    dev = jax.devices()[0]
+    print(f"~{toks / dt:.1f} batched tokens/s on {dev.platform} "
+          f"({dev.device_kind}), smoke config")
     assert stats["completed"] == args.requests
 
 

@@ -19,6 +19,7 @@ import argparse
 from dataclasses import replace
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.config import ModelConfig
 from repro.optim import AdamW
 from repro.train.loop import FailurePlan, train
@@ -52,6 +53,7 @@ def main() -> None:
     ap.add_argument("--shards", type=int, default=4,
                     help="data-parallel gradient shards (threads backend)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.arch is None:
         cfg = default_20m()

@@ -57,6 +57,7 @@ backends.
 
 from __future__ import annotations
 
+import contextlib
 import multiprocessing
 import os
 import queue
@@ -249,7 +250,8 @@ class ProcSubstrate(ThreadSubstrate):
                 args=(host_sock if start == "fork" else None,
                       child_sock, w.core_id, rt.coalesce),
                 name=f"myrmics-{w.core_id}", daemon=True)
-            proc.start()
+            with _child_environ():
+                proc.start()
             child_sock.close()
             ch = _Channel(w, host_sock, proc)
             self._channels[w.core_id] = ch
@@ -1015,6 +1017,30 @@ class _Child:
     def _complete(self, task: _ChildTask) -> None:
         dirty, self.dirty = self.dirty, {}
         self.send(Message("x_complete", (task.tid, dirty)))
+
+
+#: environment every worker process starts with.  One process at a time
+#: may hold an accelerator, and the host process may already hold it, so
+#: the workers never initialise one.  It must be in place before the
+#: child starts: a spawned child re-imports the parent's main module
+#: (and with it JAX) before ``_child_main`` runs.
+CHILD_ENV = {"JAX_PLATFORMS": "cpu"}
+
+
+@contextlib.contextmanager
+def _child_environ():
+    """Apply :data:`CHILD_ENV` to ``os.environ`` while a child starts
+    (fork copies it, spawn passes it to the new interpreter)."""
+    saved = {k: os.environ.get(k) for k in CHILD_ENV}
+    os.environ.update(CHILD_ENV)
+    try:
+        yield
+    finally:
+        for k, v in saved.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
 
 
 def _child_main(host_sock, child_sock: socket.socket,

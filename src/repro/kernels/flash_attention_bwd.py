@@ -43,8 +43,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0].astype(jnp.float32)          # (bk, d)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)        # (bq, d)
-    lse = lse_ref[0]                          # (bq,)
-    delta = delta_ref[0]                      # (bq,)
+    lse = lse_ref[0, 0]                       # (bq,) of a (1, 1, bq) block
+    delta = delta_ref[0, 0]                   # (bq,)
 
     s = scale * jnp.dot(q, k.T, preferred_element_type=jnp.float32)
     q_pos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
@@ -98,8 +98,10 @@ def flash_attention_bwd_bhsd(q, k, v, do, lse, delta, *, causal: bool,
     def kv_map(b, j, i):
         return (b, j, 0)
 
+    # lse/delta travel as (BH, 1, S): a (1, bq) block of a (BH, S) array
+    # breaks the TPU's (8, 128) tiling rule on its second-to-last dim
     def stat_map(b, j, i):
-        return (b, i)
+        return (b, 0, i)
 
     kernel = functools.partial(_bwd_kernel, causal=causal, bq=bq, bk=bk,
                                kv_len=kv_len, scale=scale)
@@ -111,8 +113,8 @@ def flash_attention_bwd_bhsd(q, k, v, do, lse, delta, *, causal: bool,
             pl.BlockSpec((1, bk, d), kv_map),
             pl.BlockSpec((1, bk, d), kv_map),
             pl.BlockSpec((1, bq, d), q_map),
-            pl.BlockSpec((1, bq), stat_map),
-            pl.BlockSpec((1, bq), stat_map),
+            pl.BlockSpec((1, 1, bq), stat_map),
+            pl.BlockSpec((1, 1, bq), stat_map),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), q_map),
@@ -129,4 +131,4 @@ def flash_attention_bwd_bhsd(q, k, v, do, lse, delta, *, causal: bool,
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, do, lse[:, None], delta[:, None])

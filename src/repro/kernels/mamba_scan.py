@@ -10,7 +10,9 @@ one write of y — the operational-intensity win the paper's CUDA kernel
 gets from shared memory.
 
 Within a chunk the recurrence is stepped with a fori_loop over L; each
-step is a (bd, N) VPU elementwise update + a (bd,) contraction.
+step reads its timestep's rows straight from the refs (``pl.ds``: the
+TPU lowering has no dynamic_slice of a loaded value), does a (bd, N) VPU
+elementwise update + a (bd,) contraction, and stores its output row.
 """
 
 from __future__ import annotations
@@ -32,25 +34,21 @@ def _scan_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, o_ref, h_ref,
         h_ref[...] = jnp.zeros_like(h_ref)
 
     a = a_ref[...].astype(jnp.float32)          # (bd, N)
-    dpar = d_ref[...].astype(jnp.float32)       # (bd,)
-    x = x_ref[0].astype(jnp.float32)            # (L, bd)
-    dt = jax.nn.softplus(dt_ref[0].astype(jnp.float32))   # (L, bd)
-    bmat = b_ref[0].astype(jnp.float32)         # (L, N)
-    cmat = c_ref[0].astype(jnp.float32)         # (L, N)
+    dpar = d_ref[...].astype(jnp.float32)       # (1, bd)
 
-    def step(t, carry):
-        h, y = carry
-        dt_t = dt[t][:, None]                   # (bd, 1)
-        decay = jnp.exp(dt_t * a)               # (bd, N)
-        h = decay * h + (dt_t * x[t][:, None]) * bmat[t][None, :]
-        y_t = jnp.sum(h * cmat[t][None, :], axis=1) + dpar * x[t]
-        y = jax.lax.dynamic_update_index_in_dim(y, y_t, t, 0)
-        return h, y
+    def step(t, h):
+        row = pl.ds(t, 1)
+        x_t = x_ref[0, row, :].astype(jnp.float32)            # (1, bd)
+        dt_t = jax.nn.softplus(dt_ref[0, row, :].astype(jnp.float32))
+        b_t = b_ref[0, row, :].astype(jnp.float32)            # (1, N)
+        c_t = c_ref[0, row, :].astype(jnp.float32)
+        decay = jnp.exp(dt_t.T * a)                           # (bd, N)
+        h = decay * h + (dt_t * x_t).T * b_t
+        y_t = jnp.sum(h * c_t, axis=1)[None, :] + dpar * x_t
+        o_ref[0, row, :] = y_t.astype(o_ref.dtype)
+        return h
 
-    y0 = jnp.zeros((chunk, x.shape[1]), jnp.float32)
-    h_fin, y = jax.lax.fori_loop(0, chunk, step, (h_ref[...], y0))
-    h_ref[...] = h_fin
-    o_ref[0] = y.astype(o_ref.dtype)
+    h_ref[...] = jax.lax.fori_loop(0, chunk, step, h_ref[...])
 
 
 def mamba_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
@@ -76,7 +74,7 @@ def mamba_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         return (i, 0)
 
     def d_map(b, i, k):
-        return (i,)
+        return (0, i)
 
     kernel = functools.partial(_scan_kernel, chunk=chunk)
     return pl.pallas_call(
@@ -88,10 +86,10 @@ def mamba_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
             pl.BlockSpec((1, chunk, n), bc_map),
             pl.BlockSpec((1, chunk, n), bc_map),
             pl.BlockSpec((bd, n), a_map),
-            pl.BlockSpec((bd,), d_map),
+            pl.BlockSpec((1, bd), d_map),
         ],
         out_specs=pl.BlockSpec((1, chunk, bd), xd_map),
         out_shape=jax.ShapeDtypeStruct((bt, s, din), x.dtype),
         scratch_shapes=[pltpu.VMEM((bd, n), jnp.float32)],
         interpret=interpret,
-    )(x, dt, B, C, A, D)
+    )(x, dt, B, C, A, D.reshape(1, din))

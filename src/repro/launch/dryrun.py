@@ -1,4 +1,6 @@
 import os
+# a host-device dry run: pinned to the CPU so it can never take the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.

@@ -8,6 +8,7 @@ from __future__ import annotations
 import argparse
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import Request, ServingEngine
 
 
@@ -19,6 +20,7 @@ def main() -> None:
     ap.add_argument("--max-new-tokens", type=int, default=8)
     ap.add_argument("--full-config", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full_config:

@@ -12,6 +12,7 @@ from __future__ import annotations
 import argparse
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import AdamW
 from repro.train.loop import FailurePlan, train
 
@@ -29,6 +30,7 @@ def main() -> None:
     ap.add_argument("--async-ckpt", action="store_true")
     ap.add_argument("--fail-at", type=int, nargs="*", default=[])
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full_config:

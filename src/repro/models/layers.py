@@ -250,7 +250,6 @@ def decode_attention_sharded(q, k_cache, v_cache, k_new, v_new, length,
     Returns (out (B, 1, Hq, D), k_cache, v_cache).
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from .sharding import get_ctx_mesh
     mesh = get_ctx_mesh()
     n_shards = mesh.shape[model_axis]
@@ -304,11 +303,11 @@ def decode_attention_sharded(q, k_cache, v_cache, k_new, v_new, length,
     dp = P(dp_axes) if dp_axes else P(None)
     rep4 = P(dp_axes if dp_axes else None, None, None, None)
     kv_spec = P(dp_axes if dp_axes else None, model_axis, None, None)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(rep4, kv_spec, kv_spec, rep4, rep4, P()),
         out_specs=(rep4, kv_spec, kv_spec),
-        check_rep=False,
+        check_vma=False,
     )(q, k_cache, v_cache, k_new, v_new, length)
 
 

@@ -10,6 +10,7 @@ cache sharded per models/sharding.cache_specs.
 
 from __future__ import annotations
 
+import time
 from collections import deque
 from dataclasses import dataclass, field
 
@@ -48,7 +49,11 @@ class ServingEngine:
         self.slot_len = np.zeros(max_batch, np.int32)
         self.cache = self.lm.init_cache(max_batch, max_len)
         self._decode = jax.jit(self.lm.decode_step)
-        self._stats = {"prefills": 0, "decode_steps": 0, "completed": 0}
+        # prefill_s / decode_s: host wall seconds in admission rounds and
+        # decode steps; both end in the host's read of the next tokens,
+        # so they include the device work
+        self._stats = {"prefills": 0, "decode_steps": 0, "completed": 0,
+                       "prefill_s": 0.0, "decode_s": 0.0}
 
     # -- helpers ----------------------------------------------------------------
 
@@ -91,7 +96,8 @@ class ServingEngine:
             # splice the single-stream cache into the batch cache
             self._splice(cache1, slot)
             self.slot_len[slot] = len(req.prompt)
-            nxt = int(jnp.argmax(logits[0]))
+            # logits span the padded vocab; its padding rows are no tokens
+            nxt = int(jnp.argmax(logits[0, :self.cfg.vocab]))
             req.out_tokens.append(nxt)
             self.slots[slot] = req
 
@@ -131,7 +137,7 @@ class ServingEngine:
         for i in active:
             self.slot_len[i] += 1
             req = self.slots[i]
-            nxt = int(jnp.argmax(logits[i]))
+            nxt = int(jnp.argmax(logits[i, :self.cfg.vocab]))
             req.out_tokens.append(nxt)
             if (len(req.out_tokens) >= req.max_new_tokens
                     or self.slot_len[i] + 1 >= self.max_len):
@@ -143,7 +149,11 @@ class ServingEngine:
         steps = 0
         while (self.queue or any(s is not None for s in self.slots)) \
                 and steps < max_steps:
+            t0 = time.perf_counter()
             self._admit()
+            t1 = time.perf_counter()
             self._step_decode()
+            self._stats["prefill_s"] += t1 - t0
+            self._stats["decode_s"] += time.perf_counter() - t1
             steps += 1
         return dict(self._stats)

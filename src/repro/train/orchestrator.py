@@ -111,20 +111,47 @@ def run_training_schedule(cfg: OrchestratorConfig) -> list[StepStats]:
     return stats
 
 
-#: per-process jit cache for the gradient tasks: keyed by arch so a
-#: forked worker process (backend="procs") compiles once and reuses the
-#: executable across every grad task it is shipped — the jitted wrapper
-#: itself cannot cross the wire, the (module-level, by-reference)
-#: factory can.
+#: per-process jit caches for the training task bodies, keyed by model
+#: config (grad; a reduced config keeps its arch_id) and by optimizer
+#: (update): a worker process (backend="procs")
+#: compiles once and reuses the executable across every task it is
+#: shipped — the jitted wrappers themselves cannot cross the wire, the
+#: (module-level, by-reference) factories can.
 _GRAD_CACHE: dict = {}
+_UPDATE_CACHE: dict = {}
 
 
 def _grad_fn_for(lm):
-    fn = _GRAD_CACHE.get(lm.cfg.arch_id)
+    fn = _GRAD_CACHE.get(lm.cfg)
     if fn is None:
         import jax
-        fn = _GRAD_CACHE[lm.cfg.arch_id] = jax.jit(jax.value_and_grad(lm.loss))
+        fn = _GRAD_CACHE[lm.cfg] = jax.jit(jax.value_and_grad(lm.loss))
     return fn
+
+
+def _update_fn_for(opt):
+    """Shard-gradient average + AdamW as one program: run op by op, the
+    update keeps float32 temporaries of every leaf alive beside the old
+    and new parameter/optimizer state, which does not fit one chip's
+    HBM at qwen2-0.5B width."""
+    fn = _UPDATE_CACHE.get(opt)
+    if fn is None:
+        import jax
+
+        def update(grads, opt_state, params):
+            avg = jax.tree.map(lambda *x: sum(x) / len(x), *grads)
+            new_params, new_state, _ = opt.update(avg, opt_state, params)
+            return new_params, new_state
+        fn = _UPDATE_CACHE[opt] = jax.jit(update)
+    return fn
+
+
+class DeviceBodiesOnProcsError(RuntimeError):
+    """Device task bodies were sent to ``backend="procs"`` on an
+    accelerator.  Its worker processes are pinned to the CPU (one process
+    may hold the chip), so they would quietly run those bodies on the
+    CPU; that needs a device-owning worker class the runtime does not
+    have yet."""
 
 
 def run_myrmics_training(model_cfg, *, seq_len: int = 64,
@@ -141,7 +168,9 @@ def run_myrmics_training(model_cfg, *, seq_len: int = 64,
     Myrmics program.  On ``backend="threads"`` the gradient tasks run
     concurrently on the worker pool (XLA releases the GIL), giving real
     multicore data parallelism; ``backend="sim"`` runs the same DAG
-    deterministically for tests.
+    deterministically for tests.  ``backend="procs"`` raises
+    :class:`DeviceBodiesOnProcsError` when JAX's default backend is an
+    accelerator.
 
     Returns ``(TrainReport, RunReport)``.
     """
@@ -153,6 +182,12 @@ def run_myrmics_training(model_cfg, *, seq_len: int = 64,
     from repro.optim import AdamW
     from repro.train.loop import TrainReport
 
+    if backend == "procs" and jax.default_backend() != "cpu":
+        raise DeviceBodiesOnProcsError(
+            f"backend='procs' pins its workers to the CPU, so on "
+            f"{jax.default_backend()!r} the grad/update bodies would not "
+            f"run on the device; device bodies need a device-owning "
+            f"worker class — use backend='threads'")
     if global_batch % n_shards:
         raise ValueError(f"global_batch={global_batch} not divisible by "
                          f"n_shards={n_shards}")
@@ -161,12 +196,19 @@ def run_myrmics_training(model_cfg, *, seq_len: int = 64,
                        total_steps=steps)
     data = TokenDataset(model_cfg, seq_len, global_batch, seed)
 
-    params0 = lm.init(jax.random.PRNGKey(seed))
-    opt0 = opt.init(params0)
     param_bytes = int(sum(x.size * x.dtype.itemsize
-                          for x in jax.tree.leaves(params0)))
+                          for x in jax.tree.leaves(lm.abstract_params())))
     per_shard = global_batch // n_shards
     report = TrainReport()
+
+    # the initial state is made by a task and lives only in the object
+    # store: held by a closure, it would stay on the device beside every
+    # later version for the whole run
+    @task
+    def init_state(ctx, p: Out, o: Out):
+        params = lm.init(jax.random.PRNGKey(seed))
+        p.write(params)
+        o.write(opt.init(params))
 
     @task
     def grad_shard(ctx, g: Out, loss_o: Out, p: In, batch: Safe):
@@ -178,16 +220,14 @@ def run_myrmics_training(model_cfg, *, seq_len: int = 64,
     @task
     def apply_update(ctx, p: InOut, o: InOut, step_r: In, gs: Safe):
         grads = [g.read() for g in gs]  # lint: allow(safe-ref-access: covered by step_r: In)
-        avg = jax.tree.map(lambda *x: sum(x) / len(x), *grads)
-        params, opt_state, _ = opt.update(avg, o.read(), p.read())
+        params, opt_state = _update_fn_for(opt)(grads, o.read(), p.read())
         p.write(params)
         o.write(opt_state)
 
     def main(ctx, root):
         p_obj = ctx.alloc(param_bytes, root, label="params")
         o_obj = ctx.alloc(param_bytes, root, label="opt")
-        ctx.write(p_obj, params0)
-        ctx.write(o_obj, opt0)
+        ctx.spawn(init_state, p_obj, o_obj, name="init")
         for step in range(steps):
             step_r = ctx.ralloc(root, 1, label=f"step{step}")
             gs = ctx.balloc(param_bytes, step_r, n_shards,

@@ -32,6 +32,11 @@ def p_bump(ctx, o: InOut, dv: Safe):
     o.write(o.read() + dv)
 
 
+@task
+def p_env(ctx, o: Out):
+    o.write(os.environ.get("JAX_PLATFORMS"))
+
+
 @pytest.mark.parametrize("nw,levels", [(1, [1]), (2, [1]), (4, [1, 2])])
 def test_procs_matches_serial_pipeline(nw, levels):
     sr = SerialRuntime()
@@ -365,3 +370,38 @@ def test_procs_snapshot_restores_torn_inflight_task(tmp_path):
     assert fs["snapshots_saved"] > 0
     assert fs["snapshots_restored"] >= 1
     assert rt.labelled_storage() == sr.labelled_storage()
+
+
+def test_procs_children_start_pinned_to_cpu(monkeypatch):
+    """Worker processes never initialise an accelerator: they start with
+    JAX_PLATFORMS=cpu even when the host's environment lacks it, and the
+    host's own environment is left as it was."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+
+    def app(ctx, root):
+        for o in ctx.balloc(8, root, 2, label="env"):
+            ctx.spawn(p_env, o)
+        yield ctx.wait([InOut(root)])
+
+    rt = Myrmics(n_workers=2, sched_levels=[1], backend="procs")
+    rt.run(app)
+    assert rt.labelled_storage() == {"env[0]": "cpu", "env[1]": "cpu"}
+    assert "JAX_PLATFORMS" not in os.environ
+
+
+def test_procs_training_refuses_accelerator_parent(monkeypatch):
+    """On a TPU host, procs workers (pinned to the CPU) would quietly
+    run the device bodies on the CPU: the training driver refuses by
+    name instead of starting any worker."""
+    import jax
+
+    from repro.configs import get_config
+    from repro.train.orchestrator import (
+        DeviceBodiesOnProcsError,
+        run_myrmics_training,
+    )
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(DeviceBodiesOnProcsError,
+                       match="device-owning worker class"):
+        run_myrmics_training(get_config("qwen2_0_5b").smoke(), steps=1,
+                             backend="procs")

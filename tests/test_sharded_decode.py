@@ -53,7 +53,7 @@ print("SHARDED_DECODE_OK", err)
 def test_sharded_decode_matches_reference():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                        capture_output=True, text=True, timeout=600,
                        cwd=os.path.dirname(os.path.dirname(__file__)))
@@ -65,7 +65,7 @@ def test_dryrun_smoke_cell():
     """End-to-end dry-run of the smallest cell in a subprocess."""
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch",
          "whisper_base", "--shape", "train_4k", "--mesh", "single",

@@ -123,6 +123,29 @@ def test_serving_engine_completes_and_deterministic():
     assert all(len(t) >= 4 for t in t1)
 
 
+def test_serving_engine_never_emits_padded_vocab():
+    """The embedding (and so the logits) is padded past the vocab; the
+    engine samples only real tokens, even when a padding row scores
+    highest."""
+    from dataclasses import replace
+
+    from repro.models.transformer import LM
+    from repro.serving import Request, ServingEngine
+    cfg = replace(get_config("qwen2_0_5b").smoke(), vocab=120)
+    assert cfg.padded_vocab > cfg.vocab
+    params = LM(cfg).init(jax.random.PRNGKey(0))
+    # rows of opposite sign: one of them outscores every real token
+    params["emb"] = params["emb"].at[cfg.vocab].set(1e3).at[
+        cfg.vocab + 1].set(-1e3)
+    eng = ServingEngine(cfg, params, max_batch=2, max_len=16, prompt_len=4)
+    reqs = [Request(rid=i, prompt=[1, 2, 3, 4], max_new_tokens=3)
+            for i in range(2)]
+    for r in reqs:
+        eng.submit(r)
+    assert eng.run()["completed"] == 2
+    assert all(0 <= t < cfg.vocab for r in reqs for t in r.out_tokens)
+
+
 def test_elastic_reshard_restore(tmp_path):
     """Checkpoint on one sharding layout, restore onto another (the
     elastic-rescale path: state re-homed onto a new mesh)."""
