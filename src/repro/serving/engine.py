@@ -6,10 +6,21 @@ inserted into the shared cache at that slot.  Greedy sampling for
 determinism.  This is the serving-side end-to-end driver (deliverable
 (b)); on real hardware the same engine runs under pjit with the decode
 cache sharded per models/sharding.cache_specs.
+
+Observability.  Host spans (``jax.profiler.TraceAnnotation``, named
+``engine.*``) land in a profiler trace on the device ops' clock and cost
+about a microsecond each when no profiler runs: ``engine.admit`` (one
+admission round that admits, ``n`` admitted), ``engine.prefill`` (one
+request's prefill, its splice into the batch cache and its first-token
+read, ``rid``), ``engine.decode`` (one decode step, ``slots`` active)
+and its three parts ``engine.decode.dispatch``, ``engine.decode.wait``
+and ``engine.decode.sample``.  ``run()`` returns the engine's cumulative
+counters (``_stats``).
 """
 
 from __future__ import annotations
 
+import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -17,6 +28,7 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models.config import ModelConfig
 from repro.models.transformer import LM
@@ -29,6 +41,42 @@ class Request:
     max_new_tokens: int = 8
     out_tokens: list[int] = field(default_factory=list)
     done: bool = False
+    #: ``time.perf_counter()`` at ``ServingEngine.submit``
+    submitted_at: float = 0.0
+
+
+#: the compile events JAX reports (their names in jax/_src/dispatch.py);
+#: one jaxpr-to-MLIR event is one lowering
+_COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                   "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                   "/jax/core/compile/backend_compile_duration")
+_LOWERING_EVENT = _COMPILE_EVENTS[1]
+
+
+class _CompileTally:
+    """Lowerings and seconds of compile events in this process, from every
+    thread; engines take the change across their own calls.  A trace
+    nested in another (a jitted call traced inside a scan) counts inside
+    both: on the eager prefill that is under 1% of the seconds."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._lowerings = 0
+        self._seconds = 0.0
+
+    def __call__(self, event: str, duration: float, **_) -> None:
+        if event in _COMPILE_EVENTS:
+            with self._lock:
+                self._seconds += duration
+                self._lowerings += event == _LOWERING_EVENT
+
+    def read(self) -> tuple[int, float]:
+        with self._lock:
+            return self._lowerings, self._seconds
+
+
+_COMPILE_TALLY = _CompileTally()
+jax.monitoring.register_event_duration_secs_listener(_COMPILE_TALLY)
 
 
 class ServingEngine:
@@ -49,11 +97,20 @@ class ServingEngine:
         self.slot_len = np.zeros(max_batch, np.int32)
         self.cache = self.lm.init_cache(max_batch, max_len)
         self._decode = jax.jit(self.lm.decode_step)
-        # prefill_s / decode_s: host wall seconds in admission rounds and
-        # decode steps; both end in the host's read of the next tokens,
-        # so they include the device work
+        # cumulative, all numeric.  prefill_s / decode_s: host wall
+        # seconds in admission rounds and decode steps; both end in the
+        # host's read of the next tokens, so they include the device work.
+        # queue_wait_s: admission minus submission, summed over admitted
+        # requests.  slots_busy / slots_idle: active and free slots summed
+        # over decode steps.  decode_{dispatch,wait,sample}_s: the parts
+        # of decode_s in the spans of those names.  compiles / compile_s:
+        # lowerings and compile-event seconds inside admission and decode
         self._stats = {"prefills": 0, "decode_steps": 0, "completed": 0,
-                       "prefill_s": 0.0, "decode_s": 0.0}
+                       "prefill_s": 0.0, "decode_s": 0.0,
+                       "queue_wait_s": 0.0, "slots_busy": 0, "slots_idle": 0,
+                       "decode_dispatch_s": 0.0, "decode_wait_s": 0.0,
+                       "decode_sample_s": 0.0, "compiles": 0,
+                       "compile_s": 0.0}
 
     # -- helpers ----------------------------------------------------------------
 
@@ -73,33 +130,40 @@ class ServingEngine:
         p = list(req.prompt)[:self.prompt_len]
         p = p + [0] * (self.prompt_len - len(p))
         req.prompt = p
+        req.submitted_at = time.perf_counter()
         self.queue.append(req)
 
     def _admit(self) -> None:
         """Admit a new batch round when all slots are free (rolling
         batches: every active slot shares one decode position, so the
         scalar cache length stays exact)."""
-        if any(s is not None for s in self.slots):
+        if any(s is not None for s in self.slots) or not self.queue:
             return
-        self.cache = self.lm.init_cache(self.max_batch, self.max_len)
-        self.slot_len[:] = 0
-        rng = np.random.default_rng(0)
-        for slot in range(self.max_batch):
-            if not self.queue:
-                continue
-            req = self.queue.popleft()
-            toks = jnp.asarray([req.prompt], jnp.int32)
-            batch = {"tokens": toks, **self._aux_batch(1, rng)}
-            cache1, logits = self.lm.prefill(self.params, batch,
-                                             max_len=self.max_len)
-            self._stats["prefills"] += 1
-            # splice the single-stream cache into the batch cache
-            self._splice(cache1, slot)
-            self.slot_len[slot] = len(req.prompt)
-            # logits span the padded vocab; its padding rows are no tokens
-            nxt = int(jnp.argmax(logits[0, :self.cfg.vocab]))
-            req.out_tokens.append(nxt)
-            self.slots[slot] = req
+        n = min(len(self.queue), self.max_batch)
+        with TraceAnnotation("engine.admit", n=n):
+            self.cache = self.lm.init_cache(self.max_batch, self.max_len)
+            self.slot_len[:] = 0
+            rng = np.random.default_rng(0)
+            for slot in range(n):
+                req = self.queue.popleft()
+                self._stats["queue_wait_s"] += (time.perf_counter()
+                                                - req.submitted_at)
+                toks = jnp.asarray([req.prompt], jnp.int32)
+                batch = {"tokens": toks, **self._aux_batch(1, rng)}
+                with TraceAnnotation("engine.prefill", rid=req.rid):
+                    cache1, logits = self.lm.prefill(self.params, batch,
+                                                     max_len=self.max_len)
+                    # splice the single-stream cache into the batch cache;
+                    # enqueued before the read, so that the read waits for
+                    # it and no splice is in flight when decode is enqueued
+                    self._splice(cache1, slot)
+                    # logits span the padded vocab; its padding rows are
+                    # no tokens
+                    nxt = int(jnp.argmax(logits[0, :self.cfg.vocab]))
+                self._stats["prefills"] += 1
+                self.slot_len[slot] = len(req.prompt)
+                req.out_tokens.append(nxt)
+                self.slots[slot] = req
 
     def _splice(self, cache1: dict, slot: int) -> None:
         def splice(dst, src):
@@ -122,38 +186,63 @@ class ServingEngine:
         active = [i for i, r in enumerate(self.slots) if r is not None]
         if not active:
             return
-        tokens = np.zeros(self.max_batch, np.int32)
-        for i in active:
-            tokens[i] = self.slots[i].out_tokens[-1]
-        # per-slot lengths differ; the shared cache["len"] is scalar, so
-        # decode at the max and mask per-slot via stored lengths: we use
-        # the max length — correctness holds because each slot's cache
-        # beyond its own length is zero-KV and masked by value
-        self.cache["len"] = jnp.asarray(int(self.slot_len[active].max()),
-                                        jnp.int32)
-        self.cache, logits = self._decode(self.params, self.cache,
-                                          jnp.asarray(tokens))
-        self._stats["decode_steps"] += 1
-        for i in active:
-            self.slot_len[i] += 1
-            req = self.slots[i]
-            nxt = int(jnp.argmax(logits[i, :self.cfg.vocab]))
-            req.out_tokens.append(nxt)
-            if (len(req.out_tokens) >= req.max_new_tokens
-                    or self.slot_len[i] + 1 >= self.max_len):
-                req.done = True
-                self._stats["completed"] += 1
-                self.slots[i] = None
+        with TraceAnnotation("engine.decode", slots=len(active)):
+            t0 = time.perf_counter()
+            with TraceAnnotation("engine.decode.dispatch"):
+                tokens = np.zeros(self.max_batch, np.int32)
+                for i in active:
+                    tokens[i] = self.slots[i].out_tokens[-1]
+                # per-slot lengths differ; the shared cache["len"] is
+                # scalar, so decode at the max and mask per-slot via stored
+                # lengths: we use the max length — correctness holds
+                # because each slot's cache beyond its own length is zero-KV
+                # and masked by value
+                self.cache["len"] = jnp.asarray(
+                    int(self.slot_len[active].max()), jnp.int32)
+                self.cache, logits = self._decode(self.params, self.cache,
+                                                  jnp.asarray(tokens))
+            t1 = time.perf_counter()
+            # logits span the padded vocab; its padding rows are no tokens
+            with TraceAnnotation("engine.decode.wait"):
+                # the first slot's read is the one that waits for the step
+                # (a separate block before it would add a host sync)
+                first = int(jnp.argmax(logits[active[0], :self.cfg.vocab]))
+            t2 = time.perf_counter()
+            with TraceAnnotation("engine.decode.sample"):
+                for i in active:
+                    self.slot_len[i] += 1
+                    req = self.slots[i]
+                    nxt = first if i == active[0] else int(
+                        jnp.argmax(logits[i, :self.cfg.vocab]))
+                    req.out_tokens.append(nxt)
+                    if (len(req.out_tokens) >= req.max_new_tokens
+                            or self.slot_len[i] + 1 >= self.max_len):
+                        req.done = True
+                        self._stats["completed"] += 1
+                        self.slots[i] = None
+            t3 = time.perf_counter()
+        st = self._stats
+        st["decode_steps"] += 1
+        st["slots_busy"] += len(active)
+        st["slots_idle"] += self.max_batch - len(active)
+        st["decode_dispatch_s"] += t1 - t0
+        st["decode_wait_s"] += t2 - t1
+        st["decode_sample_s"] += t3 - t2
 
     def run(self, max_steps: int = 1000) -> dict:
         steps = 0
         while (self.queue or any(s is not None for s in self.slots)) \
                 and steps < max_steps:
+            n0, c0 = _COMPILE_TALLY.read()
             t0 = time.perf_counter()
             self._admit()
             t1 = time.perf_counter()
             self._step_decode()
+            t2 = time.perf_counter()
+            n1, c1 = _COMPILE_TALLY.read()
             self._stats["prefill_s"] += t1 - t0
-            self._stats["decode_s"] += time.perf_counter() - t1
+            self._stats["decode_s"] += t2 - t1
+            self._stats["compiles"] += n1 - n0
+            self._stats["compile_s"] += c1 - c0
             steps += 1
         return dict(self._stats)
