@@ -213,22 +213,25 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      length: jax.Array | int) -> jax.Array:
     """Single-position GQA attention against a KV cache.
 
-    q: (B, 1, Hq, D); caches: (B, T, Hkv, D); ``length`` masks valid
-    prefix.  jnp reference path; the Pallas kernel and the seq-sharded
-    shard_map variant (serving/) implement the same contraction.
+    q: (B, 1, Hq, D); caches: (B, T, Hkv, D); ``length`` (a scalar or
+    one per batch row) masks the valid prefix.  The contraction is
+    grouped: the G = Hq / Hkv query heads of a KV group are contracted
+    together against that group as the cache stores it, in the cache's
+    dtype with float32 accumulation, so each KV group is read once and
+    the cache is never expanded to Hq heads.  The seq-sharded shard_map
+    variant below uses the same contraction.
     """
     b, _, hq, d = q.shape
-    t = k_cache.shape[1]
-    k = _expand_kv(k_cache, hq)
-    v = _expand_kv(v_cache, hq)
-    logits = jnp.einsum("bshd,bthd->bhst", q / np.sqrt(d), k,
+    t, hkv = k_cache.shape[1], k_cache.shape[2]
+    qg = (q / np.sqrt(d)).reshape(b, hkv, hq // hkv, d).astype(k_cache.dtype)
+    logits = jnp.einsum("bkgd,btkd->bkgt", qg, k_cache,
                         preferred_element_type=jnp.float32)
     mask = jnp.arange(t)[None, None, None, :] < jnp.asarray(length).reshape(-1, 1, 1, 1)
     logits = jnp.where(mask, logits, -jnp.inf)
     p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhst,bthd->bshd", p.astype(v.dtype), v,
+    out = jnp.einsum("bkgt,btkd->bkgd", p.astype(v_cache.dtype), v_cache,
                      preferred_element_type=jnp.float32)
-    return out.astype(q.dtype)
+    return out.reshape(b, 1, hq, d).astype(q.dtype)
 
 
 # -- MLPs ---------------------------------------------------------------------------
