@@ -43,6 +43,26 @@ def _span(name: str):
     return jax.profiler.TraceAnnotation(f"bench.serve.{name}")
 
 
+def served_params(ref, m: dict, mc, seed: int) -> dict:
+    """The served weights: the reference's parameter tree, drawn from
+    the seed in the configuration's dtype."""
+    return weights.serve_params(ref.param_tree(m, mc.padded_vocab), seed,
+                                weights.dtype_of(mc.param_dtype),
+                                ref.stacked_groups(m))
+
+
+def check_tree(cell, ref, mc, program_params) -> None:
+    """The program's parameter tree has the reference's leaves and shapes."""
+    import jax
+
+    want = jax.tree.map(lambda a: tuple(a.shape), program_params)
+    if want != weights.shapes_of(ref.param_tree(cell.config["model"],
+                                                mc.padded_vocab)):
+        raise harness.BenchError(
+            f"the program's parameter tree differs from bench/references/"
+            f"{cell.config['reference']}.py's")
+
+
 def check_served(cell, seed: int, sample: list, prec: str = "f32",
                  pick: str = "served") -> float:
     """Widest gap, over every sampled position, by which the picked
@@ -56,9 +76,7 @@ def check_served(cell, seed: int, sample: list, prec: str = "f32",
     m = cell.config["model"]
     mc = harness.model_config(cell.config)
     eng = cell.traffic["engine"]
-    shapes = weights.tree_shapes(m, mc.padded_vocab)
-    params = weights.serve_params(shapes, seed, weights.dtype_of(mc.param_dtype),
-                                  m["n_layers"])
+    params = served_params(ref, m, mc, seed)
     # one fixed shape: the padded prompt plus the longest possible answer
     t = eng["prompt_len"] + cell.traffic["output"]["max"]
     seqs = np.zeros((len(sample), t), np.int32)
@@ -97,13 +115,9 @@ def run(cell, *, seed: int, seconds: float, trace: bool, t_process: float,
 
     tr, m = cell.traffic, cell.config["model"]
     mc = harness.model_config(cell.config)
-    shapes = weights.tree_shapes(m, mc.padded_vocab)
-    want = jax.tree.map(lambda a: tuple(a.shape), LM(mc).abstract_params())
-    if want != jax.tree.map(tuple, shapes, is_leaf=lambda x: isinstance(x, tuple)):
-        raise harness.BenchError("the program's parameter tree differs from "
-                                 "bench/weights.py's")
-    params = weights.serve_params(shapes, seed, weights.dtype_of(mc.param_dtype),
-                                  m["n_layers"])
+    ref = harness.reference_module(cell)
+    check_tree(cell, ref, mc, LM(mc).abstract_params())
+    params = served_params(ref, m, mc, seed)
     e = tr["engine"]
     eng = ServingEngine(mc, params=params, max_batch=e["max_batch"],
                         max_len=e["max_len"], prompt_len=e["prompt_len"],
@@ -198,9 +212,9 @@ def run(cell, *, seed: int, seconds: float, trace: bool, t_process: float,
     eng_stats = {k: s1[k] - s0[k] for k in s1}
     w_item = np.dtype(weights.dtype_of(mc.param_dtype)).itemsize
     kv_item = np.dtype(weights.dtype_of(mc.compute_dtype)).itemsize
-    step_flops = [sum(flops.decode_token_flops(m, c) for c in ctx)
+    step_flops = [sum(ref.decode_token_flops(m, c) for c in ctx)
                   for ctx in decode_ctx]
-    least = [flops.least_time_s(f, flops.decode_step_bytes(m, ctx, w_item, kv_item),
+    least = [flops.least_time_s(f, ref.decode_step_bytes(m, ctx, w_item, kv_item),
                                 peak) for f, ctx in zip(step_flops, decode_ctx)]
     bounds = [b for _, b in least]
     rec = {

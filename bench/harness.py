@@ -5,7 +5,9 @@ For a cell ``<name>`` of ``BENCHMARK.json`` with ``config`` C and
 
 * ``bench/configs/C.json``   the configuration as it is run (sizes, arch
   id in ``repro.configs``, source, ``reduced``, ``assumed``, reference);
-* ``bench/references/<reference>.py``   its plain reference;
+* ``bench/references/<reference>.py``   its plain reference, which also
+  gives the architecture's parameter tree, how each leaf is drawn, and
+  the operations and bytes of its steps;
 * ``bench/traffic/T.json``   the traffic's parameters; its ``entry`` names
   the driver ``bench/entries/<entry>.py`` that generates that traffic
   against one entry point of the program;
@@ -23,7 +25,8 @@ import importlib.util
 import json
 import math
 import sys
-from dataclasses import dataclass, fields, replace
+import typing
+from dataclasses import dataclass, fields, is_dataclass, replace
 from pathlib import Path
 
 BENCH_DIR = Path(__file__).resolve().parent
@@ -43,6 +46,7 @@ class Cell:
     limits: dict         # bench/limits/<cell>.json
     end_to_end: list     # metric entries of BENCHMARK.json for this cell
     per_layer: list
+    bench_dir: Path = BENCH_DIR   # where its files were found
 
 
 def _json(path: Path) -> dict:
@@ -84,33 +88,57 @@ def load_cell(name: str, bench_dir: Path = BENCH_DIR,
         config=_json(bench_dir / "configs" / f"{w['config']}.json"),
         traffic=_json(bench_dir / "traffic" / f"{w['traffic']}.json"),
         limits=_json(limits) if limits.is_file() else {},
-        end_to_end=e2e, per_layer=per_layer)
+        end_to_end=e2e, per_layer=per_layer, bench_dir=bench_dir)
 
 
-def entry_module(cell: Cell, bench_dir: Path = BENCH_DIR):
+def entry_module(cell: Cell):
     entry = cell.traffic["entry"]
-    return load_module(bench_dir / "entries" / f"{entry}.py",
+    return load_module(cell.bench_dir / "entries" / f"{entry}.py",
                        f"bench_entry_{entry}")
 
 
-def reference_module(cell: Cell, bench_dir: Path = BENCH_DIR):
+def reference_module(cell: Cell):
     ref = cell.config["reference"]
-    return load_module(bench_dir / "references" / f"{ref}.py",
+    return load_module(cell.bench_dir / "references" / f"{ref}.py",
                        f"bench_reference_{ref}")
+
+
+def _options(cls, given: dict, where: str) -> None:
+    unknown = set(given) - {f.name for f in fields(cls)}
+    if unknown:
+        raise BenchError(f"{where} keys the program has no option for: "
+                         f"{sorted(unknown)}")
+
+
+def _group(key: str, given: dict, base, hint):
+    """A nested dataclass field built from its dict: over the preset's
+    value where it has one, else from the field's own type."""
+    cls = type(base) if is_dataclass(base) else next(
+        (t for t in (hint, *typing.get_args(hint)) if is_dataclass(t)), None)
+    if cls is None:
+        raise BenchError(f"model.{key} is a group, and the program's "
+                         f"option is not")
+    _options(cls, given, f"model.{key}")
+    try:
+        return replace(base, **given) if is_dataclass(base) else cls(**given)
+    except TypeError as e:
+        raise BenchError(f"model.{key}: {e}") from None
 
 
 def model_config(config: dict):
     """The program's ModelConfig for this configuration: the preset of
-    ``arch_id`` with every size of the file's ``model`` applied."""
+    ``arch_id`` with every size of the file's ``model`` applied; a group
+    of sizes (a dict) becomes the option's own dataclass."""
     from repro.configs import get_config
 
     base = get_config(config["arch_id"])
-    known = {f.name for f in fields(base)}
-    unknown = set(config["model"]) - known
-    if unknown:
-        raise BenchError(f"model keys the program has no option for: "
-                         f"{sorted(unknown)}")
-    return replace(base, **config["model"])
+    model = dict(config["model"])
+    _options(type(base), model, "model")
+    hints = typing.get_type_hints(type(base))
+    for k, v in model.items():
+        if isinstance(v, dict):
+            model[k] = _group(k, v, getattr(base, k), hints[k])
+    return replace(base, **model)
 
 
 def peaks(device_kind: str, bench_dir: Path = BENCH_DIR) -> dict:
@@ -121,12 +149,12 @@ def peaks(device_kind: str, bench_dir: Path = BENCH_DIR) -> dict:
     return table[device_kind]
 
 
-def read_per_layer(cell: Cell, rec: dict, bench_dir: Path = BENCH_DIR) -> dict:
+def read_per_layer(cell: Cell, rec: dict) -> dict:
     """Each per-layer metric from its own reader; a reader that finds
     nothing to read returns None and the metric is left out."""
     out = {}
     for m in cell.per_layer:
-        mod = load_module(bench_dir / "metrics" / f"{m['name']}.py",
+        mod = load_module(cell.bench_dir / "metrics" / f"{m['name']}.py",
                           f"bench_metric_{m['name'].replace('.', '_')}")
         v = mod.read(rec)
         if v is None:

@@ -1,7 +1,7 @@
 """Models, decode step: model operations of the tokens decoded in the
-window (bench/flops.py, each at its context) over the engine's own
-``decode_s`` in the window times the chip's peak, in percent.  Moves
-itl_p95_ms.
+window (the reference's ``decode_token_flops``, each at its context)
+over the engine's own ``decode_s`` in the window times the chip's peak,
+in percent.  Moves itl_p95_ms.
 """
 
 

@@ -2,9 +2,10 @@
 percent.  The least time of a decode step is the larger of its model
 operations over peak FLOP/s and its least bytes (every matmul weight
 once, plus the active slots' keys and values at their contexts) over
-HBM bandwidth (bench/flops.py, bench/peaks.json); it is averaged over
-the window's decode steps and divided by the decode program's device
-time per call in the profiler trace, found by its name.  Moves
+HBM bandwidth (the reference's ``decode_token_flops`` and
+``decode_step_bytes``, bench/flops.py, bench/peaks.json); it is averaged
+over the window's decode steps and divided by the decode program's
+device time per call in the profiler trace, found by its name.  Moves
 itl_p95_ms.
 """
 

@@ -33,6 +33,11 @@ weights drawn at random the two are one model.
 operands, ``highest`` precision) or ``"fp8"`` (both operands rounded to
 float8 e4m3 with a per-tensor scale, float32 accumulation), the control
 that has to come out as not correct.
+
+Beside the equations it gives what the harness needs of the
+architecture: the parameter tree and how each leaf is drawn
+(``param_tree``, ``stacked_groups``), and the operations and least
+bytes of decoding (``decode_token_flops``, ``decode_step_bytes``).
 """
 
 from __future__ import annotations
@@ -44,8 +49,84 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.weights import Leaf
+
 F32 = jnp.float32
 E4M3_MAX = 448.0
+
+
+# -- parameter tree -------------------------------------------------------------
+
+
+def _hd(m: dict) -> int:
+    return m.get("head_dim") or m["d_model"] // m["n_heads"]
+
+
+def param_tree(m: dict, padded_vocab: int) -> dict:
+    """The program's tree (``emb``, ``out_norm``, ``lm_head``,
+    ``blocks/{ln1,wq,wk,wv,wo,bq,bk,bv,ln2,wg,wu,wd}``): matrices
+    N(0, 0.02), output projections N(0, 0.02/sqrt(2L)), biases
+    N(0, 0.02) and norm gains 1 + N(0, 0.05), so that every term of the
+    equations is exercised."""
+    d, L = m["d_model"], m["n_layers"]
+    hd = _hd(m)
+    hq, hkv, ff = m["n_heads"] * hd, m["n_kv_heads"] * hd, m["d_ff"]
+    gain = partial(Leaf, scale=0.05, shift=1.0)
+    tree = {"emb": Leaf((padded_vocab, d)), "out_norm": gain((d,))}
+    if not m["tie_embeddings"]:
+        tree["lm_head"] = Leaf((d, padded_vocab))
+    blocks = {"ln1": gain((L, d)), "wq": Leaf((L, d, hq)),
+              "wk": Leaf((L, d, hkv)), "wv": Leaf((L, d, hkv)),
+              "wo": Leaf((L, hq, d), 0.02 / math.sqrt(2 * L))}
+    if m["qkv_bias"]:
+        blocks.update(bq=Leaf((L, hq)), bk=Leaf((L, hkv)), bv=Leaf((L, hkv)))
+    blocks.update(ln2=gain((L, d)), wg=Leaf((L, d, ff)), wu=Leaf((L, d, ff)),
+                  wd=Leaf((L, ff, d)))
+    tree["blocks"] = blocks
+    return tree
+
+
+def stacked_groups(m: dict) -> dict:
+    """Groups whose leaves carry a leading per-layer axis, and its count."""
+    return {"blocks": m["n_layers"]}
+
+
+# -- operations and bytes of decoding (see bench/flops.py) -----------------------
+
+
+def matmul_params(m: dict) -> int:
+    """Weights that each token multiplies through: every layer's q, k, v,
+    o and MLP matrices, and the output head over the real vocabulary
+    (the embedding lookup multiplies nothing)."""
+    d, hd = m["d_model"], _hd(m)
+    per_layer = (d * m["n_heads"] * hd + 2 * d * m["n_kv_heads"] * hd
+                 + m["n_heads"] * hd * d + 3 * d * m["d_ff"])
+    return m["n_layers"] * per_layer + d * m["vocab"]
+
+
+def decode_token_flops(m: dict, context: int) -> int:
+    """One decoded token that attends to ``context`` cached positions
+    (its own included)."""
+    return (2 * matmul_params(m)
+            + m["n_layers"] * m["n_heads"] * 4 * _hd(m) * context)
+
+
+def kv_bytes_per_token(m: dict, kv_itemsize: int) -> int:
+    return m["n_layers"] * 2 * m["n_kv_heads"] * _hd(m) * kv_itemsize
+
+
+def decode_step_bytes(m: dict, contexts: list[int], w_itemsize: int,
+                      kv_itemsize: int) -> int:
+    """Least HBM traffic of one decode step over the active slots: every
+    matmul weight once, the embedding rows of the step's tokens, and each
+    slot's cached keys and values up to its context."""
+    n = len(contexts)
+    weights = matmul_params(m) * w_itemsize
+    emb_rows = n * m["d_model"] * w_itemsize
+    return weights + emb_rows + kv_bytes_per_token(m, kv_itemsize) * sum(contexts)
+
+
+# -- equations ------------------------------------------------------------------
 
 
 def _fp8(x):

@@ -133,11 +133,145 @@ def test_a_new_cell_is_found_by_name_from_added_files_only(tmp_path):
     assert cell.config["name"] == "other-model"
     assert cell.traffic["arrivals"]["clients"] == 32
     assert [m["name"] for m in cell.per_layer] == ["other_metric.serve"]
-    got = harness.read_per_layer(cell, {"engine": {"prefills": 3}}, bench_dir=bench)
+    got = harness.read_per_layer(cell, {"engine": {"prefills": 3}})
     assert got == {"other_metric.serve": {"value": 6.0, "unit": "1"}}
-    assert harness.entry_module(cell, bench_dir=bench).run is not None
+    assert harness.entry_module(cell).run is not None
     # nothing that was there has changed
     assert all(p.read_bytes() == b for p, b in before.items())
+
+
+#: a reference that declares the program's mixture-of-experts tree and
+#: counts the experts a token passes through; its equations are a stub
+#: (embedding in, tied embedding out), since what is judged is the harness
+MOE_REFERENCE = """
+from bench.weights import Leaf
+
+
+def param_tree(m, padded_vocab):
+    d, L, e = m["d_model"], m["n_layers"], m["moe"]["n_experts"]
+    hd, f = m["d_model"] // m["n_heads"], m["moe"]["expert_d_ff"]
+    hq, hkv = m["n_heads"] * hd, m["n_kv_heads"] * hd
+    gain = {"scale": 0.05, "shift": 1.0}
+    return {"emb": Leaf((padded_vocab, d)), "out_norm": Leaf((d,), **gain),
+            "blocks": {"ln1": Leaf((L, d), **gain), "wq": Leaf((L, d, hq)),
+                       "wk": Leaf((L, d, hkv)), "wv": Leaf((L, d, hkv)),
+                       "wo": Leaf((L, hq, d)), "ln2": Leaf((L, d), **gain),
+                       "router": Leaf((L, d, e)), "wg": Leaf((L, e, d, f)),
+                       "wu": Leaf((L, e, d, f)), "wd": Leaf((L, e, f, d))}}
+
+
+def stacked_groups(m):
+    return {"blocks": m["n_layers"]}
+
+
+def _active(m):
+    d, moe = m["d_model"], m["moe"]
+    hd = d // m["n_heads"]
+    attn = 2 * d * m["n_heads"] * hd + 2 * d * m["n_kv_heads"] * hd
+    experts = moe["top_k"] * 3 * d * moe["expert_d_ff"]
+    return m["n_layers"] * (attn + d * moe["n_experts"] + experts) + d * m["vocab"]
+
+
+def decode_token_flops(m, context):
+    hd = m["d_model"] // m["n_heads"]
+    return 2 * _active(m) + m["n_layers"] * m["n_heads"] * 4 * hd * context
+
+
+def decode_step_bytes(m, contexts, w_itemsize, kv_itemsize):
+    hd = m["d_model"] // m["n_heads"]
+    kv = m["n_layers"] * 2 * m["n_kv_heads"] * hd * kv_itemsize
+    return _active(m) * w_itemsize + kv * sum(contexts)
+
+
+def served_hidden(params, seqs, m, prec="f32"):
+    import jax.numpy as jnp
+    return params["emb"][jnp.asarray(seqs)].astype(jnp.float32)
+
+
+def head_logits(params, x, m, prec="f32"):
+    import jax.numpy as jnp
+    return x @ params["emb"][:m["vocab"]].astype(jnp.float32).T
+"""
+
+#: granite-moe-3b's family at the program's smoke sizes
+#: (``get_config("granite_moe_3b").smoke()``)
+MOE_MODEL = {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 4,
+             "d_ff": 128, "vocab": 128, "pad_to": 16, "tie_embeddings": True,
+             "moe": {"n_experts": 4, "top_k": 2, "expert_d_ff": 64}}
+
+
+def _shapes(tree):
+    import jax
+
+    return jax.tree.map(lambda a: tuple(a.shape), tree)
+
+
+def _moe_config(**moe):
+    return {"name": "granite-moe-smoke", "arch_id": "granite_moe_3b",
+            "reference": "moe_stub",
+            "model": dict(MOE_MODEL, moe=dict(MOE_MODEL["moe"], **moe))}
+
+
+def test_a_moe_configuration_is_added_as_files_only(tmp_path):
+    from repro.models.config import MoEConfig
+    from repro.models.transformer import LM
+
+    from bench.tests.tiny import run_tiny, shrink
+
+    root = tmp_path / "checkout"
+    shutil.copytree(ROOT / "bench", root / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    bench = root / "bench"
+    before = {p: p.read_bytes() for p in bench.rglob("*") if p.is_file()}
+    (bench / "configs" / "granite-moe-smoke.json").write_text(
+        json.dumps(_moe_config()))
+    (bench / "references" / "moe_stub.py").write_text(MOE_REFERENCE)
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    name = "serve.granite-moe-smoke.chat"
+    spec["configs"].append({"name": "granite-moe-smoke", "source": "x",
+                            "file": "bench/configs/granite-moe-smoke.json",
+                            "reduced": [], "why": "x"})
+    spec["workloads"].append({"name": name, "config": "granite-moe-smoke",
+                              "traffic": "chat-closed-16", "chips": 1, "why": "x"})
+    spec["end_to_end"] = [dict(m, workloads=m["workloads"] + [name])
+                          if "workloads" in m else m for m in spec["end_to_end"]]
+    cell = harness.load_cell(name, bench_dir=bench, benchmark=spec)
+
+    mc = harness.model_config(cell.config)
+    assert isinstance(mc.moe, MoEConfig) and mc.moe.n_experts == 4
+    entry, ref = harness.entry_module(cell), harness.reference_module(cell)
+    program = LM(mc).abstract_params()
+    entry.check_tree(cell, ref, mc, program)      # raises where they differ
+    params = entry.served_params(ref, cell.config["model"], mc, 3)
+    assert params["blocks"]["wg"].shape == (2, 4, 64, 64)
+    assert _shapes(params) == _shapes(program)
+
+    out = run_tiny(shrink(cell))
+    assert set(out["metrics"]) == {m["name"] for m in cell.end_to_end}
+    assert out["attempted"] > 0 and "logit_gap" in out["checks"]
+    # nothing that was there has changed
+    assert all(p.read_bytes() == b for p, b in before.items())
+
+
+def test_an_unknown_key_inside_a_group_of_sizes_is_refused():
+    with pytest.raises(harness.BenchError, match="model.moe.*n_shared"):
+        harness.model_config(_moe_config(n_shared=1))
+
+
+def test_the_tree_check_names_the_reference():
+    from dataclasses import replace
+
+    from repro.models.transformer import LM
+
+    from bench.tests.tiny import tiny_cell
+
+    cell = tiny_cell("serve.chatglm3-6b.chat")
+    entry, ref = harness.entry_module(cell), harness.reference_module(cell)
+    mc = harness.model_config(cell.config)
+    entry.check_tree(cell, ref, mc, LM(mc).abstract_params())
+    no_bias = LM(replace(mc, qkv_bias=False)).abstract_params()
+    with pytest.raises(harness.BenchError, match="bench/references/dense_decoder.py"):
+        entry.check_tree(cell, ref, mc, no_bias)
 
 
 def test_a_reader_that_finds_nothing_leaves_its_metric_out():

@@ -5,6 +5,7 @@ and for Qwen2 (tied embedding, rotary on every head dimension), and for
 Qwen2 the loss and one AdamW update."""
 
 import copy
+import hashlib
 import json
 from dataclasses import replace
 
@@ -39,6 +40,11 @@ def _config(name, **over):
     return cfg
 
 
+def _served(m, padded_vocab, seed, dtype):
+    return weights.serve_params(ref.param_tree(m, padded_vocab), seed, dtype,
+                                ref.stacked_groups(m))
+
+
 def _program(cfg):
     from repro.models.transformer import LM
 
@@ -51,8 +57,7 @@ def test_prefill_and_cached_decode_match_the_full_forward(name):
     cfg = _config(name, param_dtype="float32", compute_dtype="float32")
     m = cfg["model"]
     lm, mc = _program(cfg)
-    params = weights.serve_params(weights.tree_shapes(m, mc.padded_vocab), 5,
-                                  jnp.float32, m["n_layers"])
+    params = _served(m, mc.padded_vocab, 5, jnp.float32)
     rng = np.random.default_rng(0)
     prompt = rng.integers(1, m["vocab"], size=(1, 12)).astype(np.int32)
     nxt = rng.integers(1, m["vocab"], size=4).astype(np.int32)
@@ -104,10 +109,9 @@ def test_qwen2_loss_and_adamw_update_match_the_program():
 def test_serve_params_are_a_function_of_the_seed():
     cfg = _config("chatglm3-6b")
     m = cfg["model"]
-    shapes = weights.tree_shapes(m, 256)
-    a = weights.serve_params(shapes, 7, jnp.bfloat16, m["n_layers"])
-    b = weights.serve_params(shapes, 7, jnp.bfloat16, m["n_layers"])
-    c = weights.serve_params(shapes, 8, jnp.bfloat16, m["n_layers"])
+    a = _served(m, 256, 7, jnp.bfloat16)
+    b = _served(m, 256, 7, jnp.bfloat16)
+    c = _served(m, 256, 8, jnp.bfloat16)
     for x, y, z in zip(jax.tree.leaves(a), jax.tree.leaves(b), jax.tree.leaves(c)):
         assert x.dtype == jnp.bfloat16
         np.testing.assert_array_equal(np.asarray(x, np.float32),
@@ -118,6 +122,43 @@ def test_serve_params_are_a_function_of_the_seed():
     abstract = lm.abstract_params()
     assert jax.tree.map(lambda x: x.shape, abstract) == \
         jax.tree.map(lambda x: x.shape, a)
+
+
+#: sha256 of the served weights at the tiny size, each leaf's path and
+#: bytes in flat-path order, computed at commit 1a782be, where
+#: bench/weights.py still wrote out the dense decoder's tree itself: the
+#: tree in the reference draws every served weight as it did
+DIGESTS = {
+    ("chatglm3-6b", "bfloat16", 5):
+        "9dc9e997eb6b18557221a7d87f262a8ee13c99ebfd657fedf61e3467f437693d",
+    ("chatglm3-6b", "bfloat16", 2**31 + 11):
+        "8cbd3e5bb32b963e7c63a8081368611b05c05fb6754cc6f7fda88dfbdbe4fffa",
+    ("chatglm3-6b", "float32", 5):
+        "3c641ce84c04cdc41c7916eaf0b183306b3f0dfa5d34b555517bc159dfa8e736",
+    ("chatglm3-6b", "float32", 2**31 + 11):
+        "c86392e7583e1707739fcf83dac80a4e8d9e8817d17c56d7bea28daae3e78768",
+    ("qwen2-0.5b", "bfloat16", 5):
+        "0bf8284535596cb6378c88f650670dcc203d0ef37ed8173d10b01849f71609eb",
+    ("qwen2-0.5b", "bfloat16", 2**31 + 11):
+        "ff91675efbf0e096b6093bc94c0c3c9ea200ca950e3c71cd8792af814d646915",
+    ("qwen2-0.5b", "float32", 5):
+        "8ad91ef8e46671b0d9e3d07e16fd1f9a8816215b36425d095bae6c0d5e5e98ca",
+    ("qwen2-0.5b", "float32", 2**31 + 11):
+        "ae653e25d9b0e447e261c511fa2a9ff0282c6c2add5977d5ba41d9b2524fca96",
+}
+
+
+@pytest.mark.parametrize("name, dtype, seed", list(DIGESTS))
+def test_served_weights_match_the_parent_digests(name, dtype, seed):
+    cfg = _config(name)
+    m = cfg["model"]
+    params = _served(m, harness.model_config(cfg).padded_vocab, seed,
+                     weights.dtype_of(dtype))
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(np.asarray(leaf).tobytes())
+    assert h.hexdigest() == DIGESTS[name, dtype, seed]
 
 
 def test_the_fp8_control_rounds_each_operand():

@@ -17,8 +17,13 @@ TINY_LIMITS = {"logit_gap": 1e-3}
 
 
 def tiny_cell(name: str, **model) -> harness.Cell:
-    cell = copy.deepcopy(harness.load_cell(name))
-    cell.config["model"].update(TINY_MODEL, **model)
+    return shrink(harness.load_cell(name), **{**TINY_MODEL, **model})
+
+
+def shrink(cell: harness.Cell, **model) -> harness.Cell:
+    """A copy of ``cell`` with the given model sizes and tiny traffic."""
+    cell = copy.deepcopy(cell)
+    cell.config["model"].update(model)
     cell.limits = dict(TINY_LIMITS)
     tr = cell.traffic
     tr["engine"] = {"max_batch": 2, "prompt_len": 16, "max_len": 40}
