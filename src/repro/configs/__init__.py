@@ -1,4 +1,4 @@
-"""Architecture registry: the 10 assigned configs + input-shape sets.
+"""Architecture registry: the assigned configs + input-shape sets.
 
 Every entry is from public literature; source tags in each module.
 ``get_config(arch_id)`` returns the full-scale config; ``.smoke()``
@@ -23,6 +23,7 @@ ARCH_IDS = [
     "granite_moe_3b",
     "zamba2_2_7b",
     "falcon_mamba_7b",
+    "granite4_h_small",
 ]
 
 # CLI aliases (--arch accepts either form)
@@ -37,6 +38,7 @@ ALIASES = {
     "granite-moe-3b-a800m": "granite_moe_3b",
     "zamba2-2.7b": "zamba2_2_7b",
     "falcon-mamba-7b": "falcon_mamba_7b",
+    "granite-4.0-h-small": "granite4_h_small",
 }
 
 

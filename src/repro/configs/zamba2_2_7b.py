@@ -2,7 +2,9 @@
 
 54L, d_model=2560, 32H (kv=32), d_ff=10240, ssm_state=64, vocab=32000.
 [arXiv:2411.15242; hf]  One shared attn+MLP block applied every 6
-Mamba2 layers (weights shared across applications).
+Mamba2 layers (weights shared across applications).  The stack runs the
+Mamba (v1) mixer of the ``hybrid`` family, so ``ssm.version`` is 1: what
+it computes; the Mamba-2 mixer is the layer pattern's (``layer_types``).
 """
 from repro.models.config import ModelConfig, SSMConfig
 
@@ -15,6 +17,6 @@ CONFIG = ModelConfig(
     n_kv_heads=32,
     d_ff=10240,
     vocab=32000,
-    ssm=SSMConfig(state_dim=64, version=2, conv_dim=4, expand=2),
+    ssm=SSMConfig(state_dim=64, version=1, conv_dim=4, expand=2),
     shared_attn_every=6,
 )

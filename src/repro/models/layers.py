@@ -112,8 +112,9 @@ def _flash_fwd_scan(qf, kc_t, vc_t, s, chunk, t, causal, q_offset):
     return out, lse
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_attention_xla(q, k, v, causal: bool, q_offset: int, chunk: int):
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_attention_xla(q, k, v, causal: bool, q_offset: int, chunk: int,
+                         scale: float):
     """Differentiable flash attention in pure XLA.
 
     Forward saves only (q, k, v, o, lse) — the KV-chunk scan's per-chunk
@@ -122,11 +123,11 @@ def _flash_attention_xla(q, k, v, causal: bool, q_offset: int, chunk: int):
     which is what keeps the memory roofline term sane at seq 4k-32k.
     q: (B, S, Hq, D); k, v already expanded to Hq heads.
     """
-    out, _ = _flash_core(q, k, v, causal, q_offset, chunk)
+    out, _ = _flash_core(q, k, v, causal, q_offset, chunk, scale)
     return out
 
 
-def _flash_core(q, k, v, causal, q_offset, chunk):
+def _flash_core(q, k, v, causal, q_offset, chunk, scale):
     b, s, hq, d = q.shape
     t = k.shape[1]
     n_chunks = (t + chunk - 1) // chunk
@@ -136,22 +137,20 @@ def _flash_core(q, k, v, causal, q_offset, chunk):
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     kc_t = jnp.moveaxis(k.reshape(b, n_chunks, chunk, hq, d), 1, 0)
     vc_t = jnp.moveaxis(v.reshape(b, n_chunks, chunk, hq, d), 1, 0)
-    scale = 1.0 / np.sqrt(d)
     qf = (q * scale).astype(q.dtype)
     acc, lse = _flash_fwd_scan(qf, kc_t, vc_t, s, chunk, t, causal, q_offset)
     return jnp.moveaxis(acc, 1, 2).astype(q.dtype), lse
 
 
-def _flash_fwd(q, k, v, causal, q_offset, chunk):
-    out, lse = _flash_core(q, k, v, causal, q_offset, chunk)
+def _flash_fwd(q, k, v, causal, q_offset, chunk, scale):
+    out, lse = _flash_core(q, k, v, causal, q_offset, chunk, scale)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, q_offset, chunk, res, dout):
+def _flash_bwd(causal, q_offset, chunk, scale, res, dout):
     q, k, v, out, lse = res
     b, s, hq, d = q.shape
     t = k.shape[1]
-    scale = 1.0 / np.sqrt(d)
     n_chunks = (t + chunk - 1) // chunk
     pad = n_chunks * chunk - t
     kp = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))) if pad else k
@@ -194,27 +193,30 @@ _flash_attention_xla.defvjp(_flash_fwd, _flash_bwd)
 
 def blocked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                       causal: bool, q_offset: int = 0,
-                      chunk: int = 512) -> jax.Array:
+                      chunk: int = 512, scale: float = 0.0) -> jax.Array:
     """Flash-style attention, scanned over KV chunks, with a flash
     custom-VJP so training never stacks per-chunk probabilities.
 
     q: (B, S, Hq, D);  k, v: (B, T, Hkv, D).  Peak temp is
-    (B, Hq, S, chunk).
+    (B, Hq, S, chunk).  ``scale`` multiplies the scores; 0 is
+    1/sqrt(D).
     """
     hq = q.shape[2]
     t = k.shape[1]
     k = _expand_kv(k, hq)
     v = _expand_kv(v, hq)
     chunk = min(chunk, t)
-    return _flash_attention_xla(q, k, v, causal, q_offset, chunk)
+    return _flash_attention_xla(q, k, v, causal, q_offset, chunk,
+                                scale or 1.0 / np.sqrt(q.shape[-1]))
 
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                     length: jax.Array | int) -> jax.Array:
+                     length: jax.Array | int, scale: float = 0.0) -> jax.Array:
     """Single-position GQA attention against a KV cache.
 
     q: (B, 1, Hq, D); caches: (B, T, Hkv, D); ``length`` (a scalar or
-    one per batch row) masks the valid prefix.  The contraction is
+    one per batch row) masks the valid prefix; ``scale`` multiplies the
+    scores, 0 is 1/sqrt(D).  The contraction is
     grouped: the G = Hq / Hkv query heads of a KV group are contracted
     together against that group as the cache stores it, in the cache's
     dtype with float32 accumulation, so each KV group is read once and
@@ -223,7 +225,8 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     """
     b, _, hq, d = q.shape
     t, hkv = k_cache.shape[1], k_cache.shape[2]
-    qg = (q / np.sqrt(d)).reshape(b, hkv, hq // hkv, d).astype(k_cache.dtype)
+    qs = q * scale if scale else q / np.sqrt(d)
+    qg = qs.reshape(b, hkv, hq // hkv, d).astype(k_cache.dtype)
     logits = jnp.einsum("bkgd,btkd->bkgt", qg, k_cache,
                         preferred_element_type=jnp.float32)
     mask = jnp.arange(t)[None, None, None, :] < jnp.asarray(length).reshape(-1, 1, 1, 1)
@@ -380,6 +383,38 @@ def moe_mlp(x: jax.Array, router_w: jax.Array, wg: jax.Array, wu: jax.Array,
     return out.reshape(b, s, d), aux
 
 
+def held_expert_mlp(x: jax.Array, router_w: jax.Array, wg: jax.Array,
+                    wu: jax.Array, wd: jax.Array, top_k: int,
+                    first: int) -> tuple[jax.Array, jax.Array]:
+    """The part of a top-k MoE layer that the experts held here give.
+
+    The router keeps its full width: x: (T, D); router_w: (D, E) over all
+    E experts; each token takes its top ``top_k`` router logits and a
+    softmax over those k (granite's TopKGating).  wg, wu: (Eh, D, F) and
+    wd: (Eh, F, D) are the held experts ``first .. first + Eh - 1``, each
+    a SwiGLU.  Every token is computed on every held expert and weighted
+    by its gate there, zero where the token did not choose the expert: no
+    capacity, no dropped token.  A token whose experts are all absent
+    gets zero from this layer.
+
+    Returns (out (T, D), routed): ``routed`` counts the (token, expert)
+    pairs that landed on a held expert; the matmuls compute T * Eh rows.
+    """
+    eh = wg.shape[0]
+    logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
+    top_v, top_i = jax.lax.top_k(logits, top_k)                  # (T, k)
+    gates = jax.nn.softmax(top_v, axis=-1)
+    hit = top_i[..., None] == first + jnp.arange(eh)              # (T, k, Eh)
+    gate = jnp.sum(jnp.where(hit, gates[..., None], 0.0), axis=-2)  # (T, Eh)
+    h = (jax.nn.silu(jnp.einsum("td,edf->etf", x, wg))
+         * jnp.einsum("td,edf->etf", x, wu))
+    # the gate folded in before the down projection, so that no
+    # (Eh, T, D) tensor is made: one contraction over (expert, F)
+    h = (h * jnp.swapaxes(gate, 0, 1)[..., None].astype(h.dtype)).astype(x.dtype)
+    out = jnp.einsum("etf,efd->td", h, wd)
+    return out, jnp.sum(hit, dtype=jnp.int32)
+
+
 # -- causal depthwise conv (mamba) ------------------------------------------------------
 
 
@@ -475,3 +510,127 @@ def selective_scan_step(x: jax.Array, dt: jax.Array, A: jax.Array,
     y = jnp.einsum("bdn,bn->bd", h_new, C.astype(jnp.float32))
     y = y + x.astype(jnp.float32) * D[None]
     return y.astype(x.dtype), h_new
+
+
+# -- Mamba-2 (SSD) -------------------------------------------------------------------------
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
+             C: jax.Array, D: jax.Array, h0: jax.Array | None = None,
+             chunk: int = 256) -> tuple[jax.Array, jax.Array]:
+    """Mamba-2's state-space scan over a whole sequence, chunked (SSD).
+
+    x: (Bt, S, H, P); dt: (Bt, S, H), after its softplus; A, D: (H,);
+    B, C: (Bt, S, G, N), head h reading group h // (H / G).  Per head
+
+        h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T     (P, N)
+        y_t = h_t C_t + D x_t
+
+    Within a chunk of L positions y is the masked quadratic (attention-
+    like) form; a ``lax.scan`` over the chunks carries the (Bt, H, P, N)
+    state between them, so the peak temporary is (Bt, L, L, H), never
+    (Bt, S, H, P, N).  float32 throughout, matmuls at full precision.
+    Positions past S are padded with dt = 0, which leaves the state as
+    it is.  Returns (y (Bt, S, H, P) float32, final state).
+    """
+    f32 = jnp.float32
+    bt, s, nh, p = x.shape
+    g, n = B.shape[2], B.shape[3]
+    r = nh // g
+    L = min(chunk, s)
+    nc = -(-s // L)
+    pad = nc * L - s
+
+    def chunks(t):
+        t = t.astype(f32)
+        if pad:
+            t = jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2))
+        return jnp.moveaxis(t.reshape(bt, nc, L, *t.shape[2:]), 1, 0)
+
+    A = A.astype(f32)
+    causal = jnp.tril(jnp.ones((L, L), bool))
+
+    def step(h, blk):
+        xb, dtb, bb, cb = blk
+        acs = jnp.cumsum(dtb * A, axis=1)                         # (Bt, L, H)
+        seg = acs[:, :, None, :] - acs[:, None, :, :]             # (Bt, i, j, H)
+        decay = jnp.exp(jnp.where(causal[None, :, :, None], seg, -jnp.inf))
+        xdt = (xb * dtb[..., None]).reshape(bt, L, g, r, p)
+        # two operands at a time, so that no (i, j, p) product is made
+        cbt = jnp.einsum("bign,bjgn->bijg", cb, bb, precision=_HIGHEST)
+        mix = cbt[..., None] * decay.reshape(bt, L, L, g, r)     # (Bt, i, j, G, R)
+        y = jnp.einsum("bijgr,bjgrp->bigrp", mix, xdt, precision=_HIGHEST)
+        hg = h.reshape(bt, g, r, p, n)
+        y = y + jnp.einsum("bign,bgrpn->bigrp", cb, hg, precision=_HIGHEST) \
+            * jnp.exp(acs).reshape(bt, L, g, r)[..., None]
+        to_end = jnp.exp(acs[:, -1:, :] - acs).reshape(bt, L, g, r)
+        h = (jnp.exp(acs[:, -1])[..., None, None] * h
+             + jnp.einsum("bjgn,bjgrp->bgrpn", bb, to_end[..., None] * xdt,
+                          precision=_HIGHEST).reshape(bt, nh, p, n))
+        y = y.reshape(bt, L, nh, p) + D.astype(f32)[:, None] * xb
+        return h, y
+
+    if h0 is None:
+        h0 = jnp.zeros((bt, nh, p, n), f32)
+    # remat per chunk: the backward recomputes the (L, L) decays
+    h, ys = jax.lax.scan(jax.checkpoint(step), h0.astype(f32),
+                         (chunks(x), chunks(dt), chunks(B), chunks(C)))
+    y = jnp.moveaxis(ys, 0, 1).reshape(bt, nc * L, nh, p)[:, :s]
+    return y, h
+
+
+def ssd_step(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
+             C: jax.Array, D: jax.Array,
+             h: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """One position of :func:`ssd_scan`: x: (Bt, H, P); dt: (Bt, H);
+    B, C: (Bt, G, N); h: (Bt, H, P, N) float32.  Returns (y float32, h)."""
+    f32 = jnp.float32
+    nh, g = x.shape[1], B.shape[1]
+    Bh = jnp.repeat(B.astype(f32), nh // g, axis=1)               # (Bt, H, N)
+    Ch = jnp.repeat(C.astype(f32), nh // g, axis=1)
+    xf = x.astype(f32)
+    dtf = dt.astype(f32)
+    decay = jnp.exp(dtf * A.astype(f32))[..., None, None]
+    h = decay * h + (dtf[..., None] * xf)[..., None] * Bh[:, :, None, :]
+    y = jnp.einsum("bhpn,bhn->bhp", h, Ch, precision=_HIGHEST)
+    return y + D.astype(f32)[:, None] * xf, h
+
+
+def mamba2_mixer(p: dict, h: jax.Array, *, headdim: int, n_groups: int,
+                 state_dim: int, chunk: int, eps: float,
+                 conv0: jax.Array | None = None,
+                 ssm0: jax.Array | None = None):
+    """Mamba-2 mixer (granite-4.0-h's mamba layer).
+
+    h: (Bt, S, D), already normed.  ``in_proj`` gives z, then x||B||C,
+    then dt (one per head); x||B||C goes through the causal depthwise
+    conv (``conv_w``, ``conv_b``) and silu; dt = softplus(dt + dt_bias);
+    A = -exp(A_log); the scan's y goes through the gated norm,
+    RMSNorm(y * silu(z)), and ``out_proj``.  ``conv0`` (Bt, K-1, C) and
+    ``ssm0`` (Bt, H, P, N) carry state in; one position with a state in
+    (a decode step) is one step of the recurrence, anything else the
+    chunked scan.  Returns (out, conv state, ssm state).
+    """
+    f32 = jnp.float32
+    bt, s, _ = h.shape
+    din = p["out_proj"].shape[0]
+    nh, gn = din // headdim, n_groups * state_dim
+    z, xbc, dt = jnp.split(h @ p["in_proj"], [din, 2 * din + 2 * gn], axis=-1)
+    xbc, conv = causal_conv1d(xbc, p["conv_w"], conv0)
+    if "conv_b" in p:
+        xbc = xbc + p["conv_b"]
+    x, B, C = jnp.split(jax.nn.silu(xbc), [din, din + gn], axis=-1)
+    x = x.reshape(bt, s, nh, headdim)
+    B = B.reshape(bt, s, n_groups, state_dim)
+    C = C.reshape(bt, s, n_groups, state_dim)
+    dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32))
+    A = -jnp.exp(p["A_log"].astype(f32))
+    if s == 1 and ssm0 is not None:
+        y, ssm = ssd_step(x[:, 0], dt[:, 0], A, B[:, 0], C[:, 0], p["D"], ssm0)
+    else:
+        y, ssm = ssd_scan(x, dt, A, B, C, p["D"], ssm0, chunk=chunk)
+    y = rms_norm(y.reshape(bt, s, din) * jax.nn.silu(z.astype(f32)),
+                 p["norm"], eps).astype(h.dtype)
+    return y @ p["out_proj"], conv, ssm

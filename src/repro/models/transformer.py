@@ -14,6 +14,11 @@ size and compile time are O(1) in depth — required for the 100-layer
                        layers every k self-attention layers (stubbed
                        patch embeddings)
 
+A config with ``layer_types`` (granite-4.0-h-style) replaces the family's
+stack by a pattern of layers of two kinds, Mamba-2 and attention, each
+followed by an MoE and a shared MLP; one stacked parameter group per
+kind, one ``lax.scan`` per run of layers of one kind.
+
 The LM class exposes: init / abstract_params / forward / loss /
 init_cache / prefill / decode_step.
 """
@@ -32,6 +37,8 @@ from .layers import (
     blocked_attention,
     causal_conv1d,
     decode_attention,
+    held_expert_mlp,
+    mamba2_mixer,
     moe_mlp,
     rms_norm,
     selective_scan,
@@ -69,10 +76,21 @@ class _Init:
 
 
 class LM:
+    #: the stacked cache leaves of each kind of the layer pattern
+    _KIND_STATE = {"mamba": ("conv", "ssm"), "attention": ("k", "v")}
+
     def __init__(self, cfg: ModelConfig):
         self.cfg = cfg
         self.pdt = _dtype(cfg.param_dtype)
         self.cdt = _dtype(cfg.compute_dtype)
+        kinds = set(cfg.layer_types)
+        if cfg.layer_types and (len(cfg.layer_types) != cfg.n_layers
+                                or not kinds <= set(self._KIND_STATE)):
+            raise ValueError(f"layer_types needs one of {sorted(self._KIND_STATE)}"
+                             f" for each of the {cfg.n_layers} layers")
+        if cfg.ssm is not None and (cfg.ssm.version == 2) != ("mamba" in kinds):
+            raise ValueError("Mamba-2 (ssm.version 2) is the mixer of the "
+                             "layer pattern's mamba layers, and only that")
 
     # ------------------------------------------------------------------ params
 
@@ -150,7 +168,10 @@ class LM:
         if not c.tie_embeddings:
             p["lm_head"] = ini.normal((c.d_model, c.padded_vocab))
         L = c.n_layers
-        if c.family in ("dense", "moe"):
+        if c.layer_types:
+            for kind, n in self._kinds().items():
+                p[kind] = self._pattern_params(ini, kind, n)
+        elif c.family in ("dense", "moe"):
             p["blocks"] = {**self._attn_params(ini, (L,)),
                            **self._mlp_params(ini, (L,))}
         elif c.family == "ssm":
@@ -201,7 +222,7 @@ class LM:
         q = q.reshape(b, s, c.n_heads, hd)
         k = k.reshape(b, s, c.n_kv_heads, hd)
         v = v.reshape(b, s, c.n_kv_heads, hd)
-        if rope:
+        if rope and c.rope_style != "none":
             q = apply_rope(q, positions, c.rope_theta, c.rope_style)
             k = apply_rope(k, positions, c.rope_theta, c.rope_style)
         return q, k, v
@@ -273,6 +294,10 @@ class LM:
         positions = jnp.arange(s)
         aux0 = jnp.float32(0.0)
 
+        if c.layer_types:
+            x, _, _ = self._walk(params, x * c.embedding_multiplier, {},
+                                 positions, None, remat=remat)
+            return self._final_hidden(params, x), aux0
         if c.family in ("dense", "moe"):
             def body(carry, bp):
                 x, aux = carry
@@ -411,6 +436,8 @@ class LM:
         hd = c.hd
         kv = (c.n_layers, batch, max_len, c.n_kv_heads, hd)
         cache: dict = {"len": jnp.zeros((), jnp.int32)}
+        if c.layer_types:
+            return {**cache, **self._pattern_cache(batch, max_len)}
         if c.family in ("dense", "moe"):
             cache["k"] = jnp.zeros(kv, self.cdt)
             cache["v"] = jnp.zeros(kv, self.cdt)
@@ -499,6 +526,8 @@ class LM:
         length = cache["len"]
         x = params["emb"][token][:, None].astype(self.cdt)   # (B, 1, D)
 
+        if c.layer_types:
+            return self._pattern_decode(params, cache, x)
         if c.family in ("dense", "moe"):
             def body(x, blk):
                 bp, kc, vc = blk
@@ -633,6 +662,12 @@ class LM:
         positions = jnp.arange(s)
         x = params["emb"][tokens].astype(self.cdt)
 
+        if c.layer_types:
+            x, state, _ = self._walk(params, x * c.embedding_multiplier,
+                                     self._state(cache), positions, None)
+            x = self._final_hidden(params, x[:, -1:])
+            logits = (x[:, 0] @ self.lm_head(params)).astype(jnp.float32)
+            return {**cache, **state, "len": jnp.asarray(s, jnp.int32)}, logits
         if c.family in ("dense", "moe"):
             def body(x, bp):
                 h = rms_norm(x, bp["ln1"], c.norm_eps)
@@ -749,3 +784,224 @@ class LM:
         logits = (x[:, -1] @ self.lm_head(params)).astype(jnp.float32)
         cache["len"] = jnp.asarray(s, jnp.int32)
         return cache, logits
+
+    def cache_batch_axes(self) -> dict:
+        """The batch axis of each leaf of :meth:`init_cache`'s cache, by
+        name: 1, after the leading per-layer axis, except the vision
+        stack's self-attention K/V (units, layers per unit, batch, ...);
+        None for the scalars (the length, counters), which hold no
+        stream's state."""
+        c = self.cfg
+        deep = ("k", "v") if c.family == "vlm" and not c.layer_types else ()
+        shapes = jax.eval_shape(lambda: self.init_cache(1, 1))
+        return {k: None if not v.shape else 2 if k in deep else 1
+                for k, v in shapes.items()}
+
+    # ------------------------------------------------------------------ layer pattern
+
+    def _kinds(self) -> dict[str, int]:
+        """Layers of each kind in ``layer_types``, in order of first use."""
+        out: dict[str, int] = {}
+        for t in self.cfg.layer_types:
+            out[t] = out.get(t, 0) + 1
+        return out
+
+    def _runs(self) -> list[tuple[str, int, int]]:
+        """``layer_types`` as runs of one kind: (kind, first, stop), the
+        indices of the run's layers in that kind's stacked group."""
+        runs: list[tuple[str, int, int]] = []
+        seen: dict[str, int] = {}
+        for t in self.cfg.layer_types:
+            i = seen.get(t, 0)
+            seen[t] = i + 1
+            if runs and runs[-1][0] == t:
+                runs[-1] = (t, runs[-1][1], i + 1)
+            else:
+                runs.append((t, i, i + 1))
+        return runs
+
+    def _pattern_params(self, ini: _Init, kind: str, n: int) -> dict:
+        """One kind's stacked group: its mixer (Mamba-2, or attention as
+        in ``_attn_params``), then ``ln2``, the router over all experts,
+        the held experts (``wg``, ``wu``: (Eh, D, F); ``wd``) and the
+        shared MLP."""
+        c = self.cfg
+        d, lead = c.d_model, (n,)
+        if kind == "mamba":
+            s = c.ssm
+            din = s.expand * d
+            nh = din // s.headdim
+            conv_ch = din + 2 * s.n_groups * s.state_dim
+            p = {"ln1": ini.ones(lead + (d,)),
+                 "in_proj": ini.normal(lead + (d, din + conv_ch + nh)),
+                 "conv_w": ini.normal(lead + (s.conv_dim, conv_ch), scale=0.1),
+                 "dt_bias": ini.zeros(lead + (nh,)),
+                 "A_log": jnp.broadcast_to(
+                     jnp.log(jnp.arange(1, nh + 1, dtype=jnp.float32)),
+                     lead + (nh,)).astype(self.pdt),
+                 "D": ini.ones(lead + (nh,)),
+                 "norm": ini.ones(lead + (din,)),
+                 "out_proj": ini.normal(lead + (din, d))}
+            if s.conv_bias:
+                p["conv_b"] = ini.zeros(lead + (conv_ch,))
+        else:
+            p = self._attn_params(ini, lead)
+        p["ln2"] = ini.ones(lead + (d,))
+        if c.moe is not None:
+            e, f = c.moe.held, c.moe.expert_d_ff
+            p.update(router=ini.normal(lead + (d, c.moe.n_experts)),
+                     wg=ini.normal(lead + (e, d, f)),
+                     wu=ini.normal(lead + (e, d, f)),
+                     wd=ini.normal(lead + (e, f, d)))
+        if c.shared_d_ff:
+            f = c.shared_d_ff
+            p.update(shared_wg=ini.normal(lead + (d, f)),
+                     shared_wu=ini.normal(lead + (d, f)),
+                     shared_wd=ini.normal(lead + (f, d)))
+        return p
+
+    def _pattern_cache(self, batch: int, max_len: int) -> dict:
+        """Per kind: ``conv`` (L, B, K-1, C) and ``ssm`` (L, B, H, P, N),
+        float32, for the mamba layers; ``k``, ``v`` for the attention
+        layers only.  With experts, two int32 counters summed over decode
+        steps: ``expert_routed``, the (token, expert) pairs that landed on
+        a held expert, and ``expert_rows``, the rows the expert matmuls
+        computed (they wrap past 2**31)."""
+        c = self.cfg
+        kinds = self._kinds()
+        out: dict = {}
+        if "mamba" in kinds:
+            s = c.ssm
+            din = s.expand * c.d_model
+            n = kinds["mamba"]
+            out["conv"] = jnp.zeros(
+                (n, batch, s.conv_dim - 1, din + 2 * s.n_groups * s.state_dim),
+                self.cdt)
+            out["ssm"] = jnp.zeros(
+                (n, batch, din // s.headdim, s.headdim, s.state_dim), jnp.float32)
+        if "attention" in kinds:
+            kv = (kinds["attention"], batch, max_len, c.n_kv_heads, c.hd)
+            out["k"] = jnp.zeros(kv, self.cdt)
+            out["v"] = jnp.zeros(kv, self.cdt)
+        if c.moe is not None:
+            out["expert_routed"] = jnp.zeros((), jnp.int32)
+            out["expert_rows"] = jnp.zeros((), jnp.int32)
+        return out
+
+    def _state(self, cache: dict) -> dict:
+        return {k: cache[k] for names in self._KIND_STATE.values()
+                for k in names if k in cache}
+
+    def _pattern_attn(self, bp: dict, h, st: dict, positions, length):
+        """Attention mixer: over the whole sequence (``length`` None;
+        writes K/V from position 0 when ``st`` holds the cache slices), or
+        one position decoded at ``length`` through the grouped
+        ``decode_attention``."""
+        c = self.cfg
+        b, s, _ = h.shape
+        q, k, v = self._qkv(bp, h, positions)
+        if length is None:
+            o = blocked_attention(q, k, v, causal=True,
+                                  scale=c.attention_multiplier)
+            if st:
+                st = {"k": jax.lax.dynamic_update_slice(
+                          st["k"], k.astype(st["k"].dtype), (0, 0, 0, 0)),
+                      "v": jax.lax.dynamic_update_slice(
+                          st["v"], v.astype(st["v"].dtype), (0, 0, 0, 0))}
+        else:
+            kc = jax.lax.dynamic_update_slice(
+                st["k"], k.astype(st["k"].dtype), (0, length, 0, 0))
+            vc = jax.lax.dynamic_update_slice(
+                st["v"], v.astype(st["v"].dtype), (0, length, 0, 0))
+            o = decode_attention(q, kc, vc, length + 1,
+                                 scale=c.attention_multiplier)
+            st = {"k": kc, "v": vc}
+        return o.reshape(b, s, -1) @ bp["wo"], st
+
+    def _pattern_mamba(self, bp: dict, h, st: dict):
+        """Mamba-2 mixer, carrying the layer's conv and ssm state where
+        ``st`` holds them (prefill and decode)."""
+        s = self.cfg.ssm
+        y, conv, ssm = mamba2_mixer(
+            bp, h, headdim=s.headdim, n_groups=s.n_groups,
+            state_dim=s.state_dim, chunk=s.chunk, eps=self.cfg.norm_eps,
+            conv0=st.get("conv"), ssm0=st.get("ssm"))
+        return y, ({"conv": conv, "ssm": ssm} if st else st)
+
+    def _pattern_layer(self, kind: str, bp: dict, x, st: dict, positions,
+                       length):
+        """x + r * mixer(norm(x)), then x + r * (experts + shared MLP)
+        of norm(x), r the residual multiplier.  Returns (x, the layer's
+        cache slices, (token, expert) pairs routed to held experts)."""
+        c = self.cfg
+        h = rms_norm(x, bp["ln1"], c.norm_eps)
+        if kind == "mamba":
+            y, st = self._pattern_mamba(bp, h, st)
+        else:
+            y, st = self._pattern_attn(bp, h, st, positions, length)
+        x = x + c.residual_multiplier * y
+        h = rms_norm(x, bp["ln2"], c.norm_eps)
+        b, s, d = h.shape
+        y = jnp.zeros_like(h)
+        routed = jnp.zeros((), jnp.int32)
+        if c.moe is not None:
+            e, routed = held_expert_mlp(
+                h.reshape(b * s, d), bp["router"], bp["wg"], bp["wu"],
+                bp["wd"], c.moe.top_k, c.moe.first_held)
+            y = e.reshape(b, s, d)
+        if c.shared_d_ff:
+            y = y + swiglu(h, bp["shared_wg"], bp["shared_wu"], bp["shared_wd"])
+        return x + c.residual_multiplier * y, st, routed
+
+    def _walk(self, params: dict, x, state: dict, positions, length,
+              remat: bool = False):
+        """The layer pattern over x, one ``lax.scan`` per run of layers of
+        one kind.  Each step takes its layer's weights, and its slices of
+        the cache leaves in ``state``, at the layer's index in its kind's
+        stacked group, and writes the slices back there.  Returns (x,
+        state, (token, expert) pairs routed to held experts)."""
+        routed = jnp.zeros((), jnp.int32)
+        for kind, first, stop in self._runs():
+            group = params[kind]
+            names = [n for n in self._KIND_STATE[kind] if n in state]
+
+            def body(carry, i, kind=kind, group=group, names=names):
+                x, st, routed = carry
+                bp = jax.tree.map(
+                    lambda t: jax.lax.dynamic_index_in_dim(t, i, 0, False), group)
+                one = {n: jax.lax.dynamic_index_in_dim(st[n], i, 0, False)
+                       for n in names}
+                x, one, r = self._pattern_layer(kind, bp, x, one, positions,
+                                                length)
+                st = {n: jax.lax.dynamic_update_index_in_dim(
+                          st[n], one[n].astype(st[n].dtype), i, 0)
+                      for n in names}
+                return (x, st, routed + r), None
+
+            if remat:
+                body = jax.checkpoint(body)
+            (x, st, routed), _ = jax.lax.scan(
+                body, (x, {n: state[n] for n in names}, routed),
+                jnp.arange(first, stop))
+            state = {**state, **st}
+        return x, state, routed
+
+    def _final_hidden(self, params: dict, x):
+        """The final norm, and the logits' divisor folded into the hidden
+        states (the head is linear), so that ``x @ lm_head`` are logits."""
+        c = self.cfg
+        return rms_norm(x, params["out_norm"], c.norm_eps) / c.logits_scaling
+
+    def _pattern_decode(self, params: dict, cache: dict, x):
+        c = self.cfg
+        length = cache["len"]
+        x, state, routed = self._walk(
+            params, x * c.embedding_multiplier, self._state(cache),
+            jnp.full((1,), length, jnp.int32), length)
+        cache = {**cache, **state, "len": length + 1}
+        if c.moe is not None:
+            cache["expert_routed"] = cache["expert_routed"] + routed
+            cache["expert_rows"] = (cache["expert_rows"]
+                                    + x.shape[0] * c.moe.held * c.n_layers)
+        x = self._final_hidden(params, x)
+        return cache, (x[:, 0] @ self.lm_head(params)).astype(jnp.float32)

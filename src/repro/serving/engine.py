@@ -12,10 +12,13 @@ Observability.  Host spans (``jax.profiler.TraceAnnotation``, named
 about a microsecond each when no profiler runs: ``engine.admit`` (one
 admission round that admits, ``n`` admitted), ``engine.prefill`` (one
 request's prefill, its splice into the batch cache and its first-token
-read, ``rid``), ``engine.decode`` (one decode step, ``slots`` active)
-and its three parts ``engine.decode.dispatch``, ``engine.decode.wait``
-and ``engine.decode.sample``.  ``run()`` returns the engine's cumulative
-counters (``_stats``).
+read, ``rid``), inside it ``engine.splice`` (placing the request's
+state into its slot, ``rid``), ``engine.decode`` (one decode step,
+``slots`` active) and its three parts ``engine.decode.dispatch``,
+``engine.decode.wait`` and ``engine.decode.sample``.  ``run()`` returns
+the engine's cumulative counters (``_stats``), and the model's own
+counters that its decode step keeps in the cache, summed on the device
+and returned as device scalars, so that no step waits to read them.
 """
 
 from __future__ import annotations
@@ -24,6 +27,7 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -79,6 +83,14 @@ _COMPILE_TALLY = _CompileTally()
 jax.monitoring.register_event_duration_secs_listener(_COMPILE_TALLY)
 
 
+def _splice_leaves(batch: dict, single: dict, slot, axes: dict) -> dict:
+    """Each leaf of a single-stream cache placed at ``slot`` of the batch
+    cache's leaf of that name, along the leaf's batch axis."""
+    return {k: jax.lax.dynamic_update_slice_in_dim(
+                batch[k], single[k].astype(batch[k].dtype), slot, axes[k])
+            for k in batch}
+
+
 class ServingEngine:
     def __init__(self, cfg: ModelConfig, params=None, *, max_batch: int = 4,
                  max_len: int = 64, prompt_len: int = 8, seed: int = 0):
@@ -97,6 +109,18 @@ class ServingEngine:
         self.slot_len = np.zeros(max_batch, np.int32)
         self.cache = self.lm.init_cache(max_batch, max_len)
         self._decode = jax.jit(self.lm.decode_step)
+        # one program for every prompt (all are padded to prompt_len):
+        # run eagerly, each scan of the prefill would be lowered again
+        # for every request
+        self._prefill = jax.jit(self.lm.prefill, static_argnames=("max_len",))
+        axes = self.lm.cache_batch_axes()
+        # the leaves that hold per-stream state, and the scalars that the
+        # decode step sums over steps (all but the length); the batch
+        # leaves are spliced in place
+        self._per_stream = {k: a for k, a in axes.items() if a is not None}
+        self._counters = [k for k, a in axes.items() if a is None and k != "len"]
+        self._splice_jit = jax.jit(partial(_splice_leaves, axes=self._per_stream),
+                                   donate_argnums=0)
         # cumulative, all numeric.  prefill_s / decode_s: host wall
         # seconds in admission rounds and decode steps; both end in the
         # host's read of the next tokens, so they include the device work.
@@ -104,13 +128,14 @@ class ServingEngine:
         # requests.  slots_busy / slots_idle: active and free slots summed
         # over decode steps.  decode_{dispatch,wait,sample}_s: the parts
         # of decode_s in the spans of those names.  compiles / compile_s:
-        # lowerings and compile-event seconds inside admission and decode
+        # lowerings and compile-event seconds inside admission and decode.
+        # splice_s: host seconds placing prefilled state into slots
         self._stats = {"prefills": 0, "decode_steps": 0, "completed": 0,
                        "prefill_s": 0.0, "decode_s": 0.0,
                        "queue_wait_s": 0.0, "slots_busy": 0, "slots_idle": 0,
                        "decode_dispatch_s": 0.0, "decode_wait_s": 0.0,
                        "decode_sample_s": 0.0, "compiles": 0,
-                       "compile_s": 0.0}
+                       "compile_s": 0.0, "splice_s": 0.0}
 
     # -- helpers ----------------------------------------------------------------
 
@@ -141,7 +166,9 @@ class ServingEngine:
             return
         n = min(len(self.queue), self.max_batch)
         with TraceAnnotation("engine.admit", n=n):
-            self.cache = self.lm.init_cache(self.max_batch, self.max_len)
+            # a fresh cache for the round; the step counters run on
+            self.cache = {**self.lm.init_cache(self.max_batch, self.max_len),
+                          **{k: self.cache[k] for k in self._counters}}
             self.slot_len[:] = 0
             rng = np.random.default_rng(0)
             for slot in range(n):
@@ -151,12 +178,15 @@ class ServingEngine:
                 toks = jnp.asarray([req.prompt], jnp.int32)
                 batch = {"tokens": toks, **self._aux_batch(1, rng)}
                 with TraceAnnotation("engine.prefill", rid=req.rid):
-                    cache1, logits = self.lm.prefill(self.params, batch,
-                                                     max_len=self.max_len)
+                    cache1, logits = self._prefill(self.params, batch,
+                                                   max_len=self.max_len)
                     # splice the single-stream cache into the batch cache;
                     # enqueued before the read, so that the read waits for
                     # it and no splice is in flight when decode is enqueued
-                    self._splice(cache1, slot)
+                    with TraceAnnotation("engine.splice", rid=req.rid):
+                        t0 = time.perf_counter()
+                        self._splice(cache1, slot)
+                        self._stats["splice_s"] += time.perf_counter() - t0
                     # logits span the padded vocab; its padding rows are
                     # no tokens
                     nxt = int(jnp.argmax(logits[0, :self.cfg.vocab]))
@@ -166,21 +196,12 @@ class ServingEngine:
                 self.slots[slot] = req
 
     def _splice(self, cache1: dict, slot: int) -> None:
-        def splice(dst, src):
-            if dst.ndim == 0:
-                return dst
-            # batch dim: index where shapes differ by max_batch vs 1
-            for axis in range(dst.ndim):
-                if dst.shape[axis] == self.max_batch and src.shape[axis] == 1:
-                    idx = [slice(None)] * dst.ndim
-                    idx[axis] = slice(slot, slot + 1)
-                    return dst.at[tuple(idx)].set(src.astype(dst.dtype))
-            return dst
-        self.cache = {
-            k: (splice(self.cache[k], cache1[k]) if k != "len" else
-                self.cache[k])
-            for k in self.cache
-        }
+        """Place a single-stream cache into ``slot``, leaf by leaf by name
+        along the batch axis the model declares (``cache_batch_axes``)."""
+        names = self._per_stream
+        self.cache = {**self.cache, **self._splice_jit(
+            {k: self.cache[k] for k in names}, {k: cache1[k] for k in names},
+            slot)}
 
     def _step_decode(self) -> None:
         active = [i for i, r in enumerate(self.slots) if r is not None]
@@ -204,17 +225,17 @@ class ServingEngine:
             t1 = time.perf_counter()
             # logits span the padded vocab; its padding rows are no tokens
             with TraceAnnotation("engine.decode.wait"):
-                # the first slot's read is the one that waits for the step
-                # (a separate block before it would add a host sync)
-                first = int(jnp.argmax(logits[active[0], :self.cfg.vocab]))
+                # every slot's token in one read, which waits for the step
+                # (a separate block before it would add a host sync; a read
+                # per slot would cost a device-to-host round trip each)
+                nxt_all = np.asarray(jnp.argmax(logits[:, :self.cfg.vocab],
+                                                axis=-1))
             t2 = time.perf_counter()
             with TraceAnnotation("engine.decode.sample"):
                 for i in active:
                     self.slot_len[i] += 1
                     req = self.slots[i]
-                    nxt = first if i == active[0] else int(
-                        jnp.argmax(logits[i, :self.cfg.vocab]))
-                    req.out_tokens.append(nxt)
+                    req.out_tokens.append(int(nxt_all[i]))
                     if (len(req.out_tokens) >= req.max_new_tokens
                             or self.slot_len[i] + 1 >= self.max_len):
                         req.done = True
@@ -245,4 +266,4 @@ class ServingEngine:
             self._stats["compiles"] += n1 - n0
             self._stats["compile_s"] += c1 - c0
             steps += 1
-        return dict(self._stats)
+        return {**self._stats, **{k: self.cache[k] for k in self._counters}}

@@ -13,7 +13,7 @@ from repro.configs import get_config
 from repro.serving import Request, ServingEngine
 
 MAX_BATCH = 2
-SPANS = ("engine.admit", "engine.prefill", "engine.decode",
+SPANS = ("engine.admit", "engine.prefill", "engine.splice", "engine.decode",
          "engine.decode.dispatch", "engine.decode.wait",
          "engine.decode.sample")
 
@@ -58,6 +58,12 @@ def served():
 def test_counters_are_numeric(served):
     _, s1, _, _ = served
     assert all(isinstance(v, (int, float)) for v in s1.values())
+
+
+def test_splice_time_lies_within_admission(served):
+    s0, s1, _, _ = served
+    d = {k: s1[k] - s0[k] for k in s1}
+    assert 0 < d["splice_s"] < d["prefill_s"]
 
 
 def test_slots_add_up_to_steps_times_batch(served):
@@ -145,6 +151,8 @@ def test_spans_in_a_profiler_trace(tmp_path):
                    for e in inner)
 
     assert inside("engine.prefill", "engine.admit")
+    assert inside("engine.splice", "engine.prefill")
     for part in ("dispatch", "wait", "sample"):
         assert inside(f"engine.decode.{part}", "engine.decode")
     assert sum(e.name == "engine.prefill" for e in ours) == 3
+    assert sum(e.name == "engine.splice" for e in ours) == 3
