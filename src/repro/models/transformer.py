@@ -529,13 +529,30 @@ class LM:
         if c.layer_types:
             return self._pattern_decode(params, cache, x)
         if c.family in ("dense", "moe"):
-            def body(x, blk):
-                bp, kc, vc = blk
+            # K and V ride in the carry, so that a donated cache is
+            # updated in place (as the scan's outputs they would fill a
+            # new buffer on every step), and only the token's position
+            # is written back: the whole layer slice would be written
+            # otherwise.  A sequence-sharded slice goes back whole, each
+            # shard its own part.
+            def put(stack, new, i):
+                if c.sharded_decode:
+                    return jax.lax.dynamic_update_index_in_dim(stack, new, i, 0)
+                row = jax.lax.dynamic_slice_in_dim(new, length, 1, 1)
+                return jax.lax.dynamic_update_slice(stack, row[None],
+                                                    (i, 0, length, 0, 0))
+
+            def body(carry, blk):
+                x, ks, vs = carry
+                bp, i = blk
+                kc = jax.lax.dynamic_index_in_dim(ks, i, 0, False)
+                vc = jax.lax.dynamic_index_in_dim(vs, i, 0, False)
                 x, kc, vc = self._attn_decode(bp, x, kc, vc, length)
                 x, _ = self._mlp(bp, x)
-                return x, (kc, vc)
-            x, (ks, vs) = jax.lax.scan(
-                body, x, (params["blocks"], cache["k"], cache["v"]))
+                return (x, put(ks, kc, i), put(vs, vc, i)), None
+            (x, ks, vs), _ = jax.lax.scan(
+                body, (x, cache["k"], cache["v"]),
+                (params["blocks"], jnp.arange(c.n_layers)))
             cache = {**cache, "k": ks, "v": vs}
 
         elif c.family == "ssm":

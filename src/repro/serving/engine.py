@@ -19,6 +19,12 @@ state into its slot, ``rid``), ``engine.decode`` (one decode step,
 the engine's cumulative counters (``_stats``), and the model's own
 counters that its decode step keeps in the cache, summed on the device
 and returned as device scalars, so that no step waits to read them.
+
+The cache is donated to the decode program, which writes each step's
+K/V position and recurrent state into the buffers it was given.  A step
+therefore deletes the cache it was passed; the model's counters go in
+as a separate, undonated argument, so that the scalars ``run()`` returned
+stay readable after later steps.
 """
 
 from __future__ import annotations
@@ -108,7 +114,6 @@ class ServingEngine:
         self.slots: list[Request | None] = [None] * max_batch
         self.slot_len = np.zeros(max_batch, np.int32)
         self.cache = self.lm.init_cache(max_batch, max_len)
-        self._decode = jax.jit(self.lm.decode_step)
         # one program for every prompt (all are padded to prompt_len):
         # run eagerly, each scan of the prefill would be lowered again
         # for every request
@@ -119,6 +124,13 @@ class ServingEngine:
         # leaves are spliced in place
         self._per_stream = {k: a for k, a in axes.items() if a is not None}
         self._counters = [k for k, a in axes.items() if a is None and k != "len"]
+        lm = self.lm
+
+        def decode_step(params, cache, counters, tokens):
+            # the model's counters apart from the rest of the cache, so
+            # that the rest alone is donated; returns the whole new cache
+            return lm.decode_step(params, {**cache, **counters}, tokens)
+        self._decode_jit = jax.jit(decode_step, donate_argnums=1)
         self._splice_jit = jax.jit(partial(_splice_leaves, axes=self._per_stream),
                                    donate_argnums=0)
         # cumulative, all numeric.  prefill_s / decode_s: host wall
@@ -194,6 +206,28 @@ class ServingEngine:
                 self.slot_len[slot] = len(req.prompt)
                 req.out_tokens.append(nxt)
                 self.slots[slot] = req
+
+    def _split(self, cache: dict) -> tuple[dict, dict]:
+        counters = {k: cache[k] for k in self._counters}
+        return {k: v for k, v in cache.items() if k not in counters}, counters
+
+    def _decode(self, params, cache: dict, tokens) -> tuple[dict, jax.Array]:
+        """One decode step.  Deletes every leaf of ``cache`` but the
+        counters: the new cache is written into their buffers."""
+        return self._decode_jit(params, *self._split(cache), tokens)
+
+    def decode_alias_bytes(self) -> int:
+        """Bytes of the decode program's outputs that alias its donated
+        inputs, from XLA's memory analysis of the program compiled for
+        the engine's shapes (compiled on demand).  Zero means the cache
+        is copied into a new output on every step."""
+        shape = partial(jax.tree.map,
+                        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype))
+        tokens = jax.ShapeDtypeStruct((self.max_batch,), jnp.int32)
+        compiled = self._decode_jit.lower(
+            shape(self.params), *map(shape, self._split(self.cache)),
+            tokens).compile()
+        return int(compiled.memory_analysis().alias_size_in_bytes)
 
     def _splice(self, cache1: dict, slot: int) -> None:
         """Place a single-stream cache into ``slot``, leaf by leaf by name
