@@ -18,5 +18,5 @@ CONFIG = ModelConfig(
     d_ff=2048,
     vocab=51865,
     mlp_type="gelu",       # whisper uses 2-matrix GELU MLPs
-    rope_style="full",     # decoder uses learned positions (rope=False paths)
+    rope_style="none",     # learned decoder positions; none in the encoder
 )

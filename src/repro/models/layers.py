@@ -211,12 +211,18 @@ def blocked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                     length: jax.Array | int, scale: float = 0.0) -> jax.Array:
+                     length: jax.Array | int, scale: float = 0.0,
+                     k_new: jax.Array | None = None,
+                     v_new: jax.Array | None = None) -> jax.Array:
     """Single-position GQA attention against a KV cache.
 
     q: (B, 1, Hq, D); caches: (B, T, Hkv, D); ``length`` (a scalar or
     one per batch row) masks the valid prefix; ``scale`` multiplies the
-    scores, 0 is 1/sqrt(D).  The contraction is
+    scores, 0 is 1/sqrt(D).  ``k_new``, ``v_new`` (B, 1, Hkv, D), when
+    given, are the position's own K/V, attended beside the cache's
+    first ``length`` positions: the cache need not hold them yet, so a
+    decode step reads its layer's cache as stored and writes the new
+    row apart.  The contraction is
     grouped: the G = Hq / Hkv query heads of a KV group are contracted
     together against that group as the cache stores it, in the cache's
     dtype with float32 accumulation, so each KV group is read once and
@@ -231,9 +237,15 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                         preferred_element_type=jnp.float32)
     mask = jnp.arange(t)[None, None, None, :] < jnp.asarray(length).reshape(-1, 1, 1, 1)
     logits = jnp.where(mask, logits, -jnp.inf)
+    if k_new is not None:
+        own = jnp.einsum("bkgd,bkd->bkg", qg, k_new[:, 0].astype(k_cache.dtype),
+                         preferred_element_type=jnp.float32)
+        logits = jnp.concatenate([logits, own[..., None]], axis=-1)
     p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgt,btkd->bkgd", p.astype(v_cache.dtype), v_cache,
-                     preferred_element_type=jnp.float32)
+    out = jnp.einsum("bkgt,btkd->bkgd", p[..., :t].astype(v_cache.dtype),
+                     v_cache, preferred_element_type=jnp.float32)
+    if v_new is not None:
+        out = out + p[..., t:] * v_new[:, 0].astype(jnp.float32)[:, :, None]
     return out.reshape(b, 1, hq, d).astype(q.dtype)
 
 
@@ -241,7 +253,8 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 
 
 def decode_attention_sharded(q, k_cache, v_cache, k_new, v_new, length,
-                             *, dp_axes: tuple, model_axis: str = "model"):
+                             *, dp_axes: tuple, model_axis: str = "model",
+                             scale: float = 0.0):
     """Flash-decode with the KV cache sequence-sharded over the model
     axis (one shard_map: local cache update + partial softmax + psum
     combine).
@@ -252,7 +265,8 @@ def decode_attention_sharded(q, k_cache, v_cache, k_new, v_new, length,
     owning shard and the softmax is stitched with three tiny psums —
     the EXPERIMENTS.md SPerf decode iteration.
 
-    q: (B, 1, Hq, D); caches: (B, T, Hkv, D); k_new/v_new: (B, 1, Hkv, D).
+    q: (B, 1, Hq, D); caches: (B, T, Hkv, D); k_new/v_new: (B, 1, Hkv, D);
+    ``scale`` multiplies the scores, 0 is 1/sqrt(D).
     Returns (out (B, 1, Hq, D), k_cache, v_cache).
     """
     from jax.sharding import PartitionSpec as P
@@ -261,7 +275,7 @@ def decode_attention_sharded(q, k_cache, v_cache, k_new, v_new, length,
     n_shards = mesh.shape[model_axis]
     t = k_cache.shape[1]
     t_local = t // n_shards
-    scale = 1.0 / np.sqrt(q.shape[-1])
+    scale = scale or 1.0 / np.sqrt(q.shape[-1])
 
     def local(q, kc, vc, kn, vn, length):
         sid = jax.lax.axis_index(model_axis)

@@ -6,7 +6,7 @@ size and compile time are O(1) in depth — required for the 100-layer
 
   dense | moe          decoder-only LM (GQA + RoPE [+ MoE MLP])
   ssm                  attention-free Mamba stack (falcon-mamba)
-  hybrid               Mamba2-style stack + one *shared* attention block
+  hybrid               Mamba stack + one *shared* attention block
                        applied every k layers (zamba2)
   encdec               Whisper-style: non-causal encoder over stubbed
                        frame embeddings + causal decoder w/ cross-attn
@@ -14,10 +14,13 @@ size and compile time are O(1) in depth — required for the 100-layer
                        layers every k self-attention layers (stubbed
                        patch embeddings)
 
-A config with ``layer_types`` (granite-4.0-h-style) replaces the family's
-stack by a pattern of layers of two kinds, Mamba-2 and attention, each
-followed by an MoE and a shared MLP; one stacked parameter group per
-kind, one ``lax.scan`` per run of layers of one kind.
+The dense and moe families, and a config with ``layer_types``
+(granite-4.0-h-style), are a layer pattern that ``_walk`` runs: layers
+of two kinds, Mamba-2 and attention, each followed by its second half
+(``_mlp``); one stacked parameter group per kind, one ``lax.scan`` per
+run of layers of one kind.  Every self-attention of every family is
+``_attention``, and every cache entry is read with ``_take`` and written
+with ``_put``.
 
 The LM class exposes: init / abstract_params / forward / loss /
 init_cache / prefill / decode_step.
@@ -37,6 +40,7 @@ from .layers import (
     blocked_attention,
     causal_conv1d,
     decode_attention,
+    decode_attention_sharded,
     held_expert_mlp,
     mamba2_mixer,
     moe_mlp,
@@ -52,6 +56,10 @@ MAX_LEARNED_POS = 32768  # learned-position table (whisper-style decoder)
 def _dtype(name: str):
     return {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
             "float16": jnp.float16}[name]
+
+
+def _pick(d: dict, names) -> dict:
+    return {n: d[n] for n in names if n in d}
 
 
 class _Init:
@@ -76,7 +84,7 @@ class _Init:
 
 
 class LM:
-    #: the stacked cache leaves of each kind of the layer pattern
+    #: the stacked cache leaves of each kind of layer that ``_walk`` runs
     _KIND_STATE = {"mamba": ("conv", "ssm"), "attention": ("k", "v")}
 
     def __init__(self, cfg: ModelConfig):
@@ -113,28 +121,40 @@ class LM:
         return p
 
     def _mlp_params(self, ini: _Init, lead: tuple[int, ...] = ()) -> dict:
+        """The second half of a layer (``_mlp``): ``ln2``, then under a
+        layer pattern the router over all experts, the held experts
+        (``wg``, ``wu``: (Eh, D, F); ``wd``) and the shared MLP; else the
+        family's MoE or dense MLP."""
         c = self.cfg
+        d = c.d_model
+        p = {"ln2": ini.ones(lead + (d,))}
+        if c.layer_types:
+            if c.moe is not None:
+                e, f = c.moe.held, c.moe.expert_d_ff
+                p.update(router=ini.normal(lead + (d, c.moe.n_experts)),
+                         wg=ini.normal(lead + (e, d, f)),
+                         wu=ini.normal(lead + (e, d, f)),
+                         wd=ini.normal(lead + (e, f, d)))
+            if c.shared_d_ff:
+                f = c.shared_d_ff
+                p.update(shared_wg=ini.normal(lead + (d, f)),
+                         shared_wu=ini.normal(lead + (d, f)),
+                         shared_wd=ini.normal(lead + (f, d)))
+            return p
         if c.moe is not None:
             e, f = c.moe.n_experts, c.moe.expert_d_ff
-            return {
-                "ln2": ini.ones(lead + (c.d_model,)),
-                "router": ini.normal(lead + (c.d_model, e)),
-                "wg": ini.normal(lead + (e, c.d_model, f)),
-                "wu": ini.normal(lead + (e, c.d_model, f)),
-                "wd": ini.normal(lead + (e, f, c.d_model)),
-            }
-        if c.mlp_type == "gelu":
-            return {
-                "ln2": ini.ones(lead + (c.d_model,)),
-                "wu": ini.normal(lead + (c.d_model, c.d_ff)),
-                "wd": ini.normal(lead + (c.d_ff, c.d_model)),
-            }
-        return {
-            "ln2": ini.ones(lead + (c.d_model,)),
-            "wg": ini.normal(lead + (c.d_model, c.d_ff)),
-            "wu": ini.normal(lead + (c.d_model, c.d_ff)),
-            "wd": ini.normal(lead + (c.d_ff, c.d_model)),
-        }
+            p.update(router=ini.normal(lead + (d, e)),
+                     wg=ini.normal(lead + (e, d, f)),
+                     wu=ini.normal(lead + (e, d, f)),
+                     wd=ini.normal(lead + (e, f, d)))
+        elif c.mlp_type == "gelu":
+            p.update(wu=ini.normal(lead + (d, c.d_ff)),
+                     wd=ini.normal(lead + (c.d_ff, d)))
+        else:
+            p.update(wg=ini.normal(lead + (d, c.d_ff)),
+                     wu=ini.normal(lead + (d, c.d_ff)),
+                     wd=ini.normal(lead + (c.d_ff, d)))
+        return p
 
     def _ssm_params(self, ini: _Init, lead: tuple[int, ...] = ()) -> dict:
         c = self.cfg
@@ -158,6 +178,33 @@ class LM:
             "out_proj": ini.normal(lead + (din, c.d_model)),
         }
 
+    def _mamba2_params(self, ini: _Init, lead: tuple[int, ...]) -> dict:
+        c = self.cfg
+        s = c.ssm
+        d = c.d_model
+        din = s.expand * d
+        nh = din // s.headdim
+        conv_ch = din + 2 * s.n_groups * s.state_dim
+        p = {"ln1": ini.ones(lead + (d,)),
+             "in_proj": ini.normal(lead + (d, din + conv_ch + nh)),
+             "conv_w": ini.normal(lead + (s.conv_dim, conv_ch), scale=0.1),
+             "dt_bias": ini.zeros(lead + (nh,)),
+             "A_log": jnp.broadcast_to(
+                 jnp.log(jnp.arange(1, nh + 1, dtype=jnp.float32)),
+                 lead + (nh,)).astype(self.pdt),
+             "D": ini.ones(lead + (nh,)),
+             "norm": ini.ones(lead + (din,)),
+             "out_proj": ini.normal(lead + (din, d))}
+        if s.conv_bias:
+            p["conv_b"] = ini.zeros(lead + (conv_ch,))
+        return p
+
+    def _layer_params(self, ini: _Init, kind: str, n: int) -> dict:
+        """One kind's stacked group of ``n`` layers: its mixer (Mamba-2,
+        or attention as in ``_attn_params``), then its second half."""
+        mixer = (self._mamba2_params if kind == "mamba" else self._attn_params)
+        return {**mixer(ini, (n,)), **self._mlp_params(ini, (n,))}
+
     def init(self, rng: jax.Array) -> dict:
         c = self.cfg
         ini = _Init(rng, self.pdt)
@@ -168,18 +215,14 @@ class LM:
         if not c.tie_embeddings:
             p["lm_head"] = ini.normal((c.d_model, c.padded_vocab))
         L = c.n_layers
-        if c.layer_types:
+        if self._layers():
             for kind, n in self._kinds().items():
-                p[kind] = self._pattern_params(ini, kind, n)
-        elif c.family in ("dense", "moe"):
-            p["blocks"] = {**self._attn_params(ini, (L,)),
-                           **self._mlp_params(ini, (L,))}
-        elif c.family == "ssm":
+                p[self._group(kind)] = self._layer_params(ini, kind, n)
+        elif c.family in ("ssm", "hybrid"):
             p["blocks"] = self._ssm_params(ini, (L,))
-        elif c.family == "hybrid":
-            p["blocks"] = self._ssm_params(ini, (L,))
-            p["shared_attn"] = {**self._attn_params(ini),
-                                **self._mlp_params(ini)}
+            if c.family == "hybrid":
+                p["shared_attn"] = {**self._attn_params(ini),
+                                    **self._mlp_params(ini)}
         elif c.family == "encdec":
             p["pos_enc"] = ini.normal((c.enc_seq, c.d_model))
             p["pos_dec"] = ini.normal((MAX_LEARNED_POS, c.d_model))
@@ -193,7 +236,6 @@ class LM:
         elif c.family == "vlm":
             k = c.cross_attn_every
             units = L // k
-            selfs = units * (k - 1)
             p["blocks"] = {**self._attn_params(ini, (units, k - 1)),
                            **self._mlp_params(ini, (units, k - 1))}
             cross = self._attn_params(ini, (units,), cross=True)
@@ -208,9 +250,29 @@ class LM:
     def abstract_params(self) -> dict:
         return jax.eval_shape(lambda: self.init(jax.random.PRNGKey(0)))
 
-    # ------------------------------------------------------------------ attention pieces
+    # ------------------------------------------------------------------ cache entries
 
-    def _qkv(self, bp: dict, h: jax.Array, positions, rope: bool = True):
+    @staticmethod
+    def _take(stack: jax.Array, at: tuple) -> jax.Array:
+        """The entry of a stacked cache leaf at the leading indices ``at``
+        (a layer; a unit and a layer of the vision stack)."""
+        n = len(at)
+        return jax.lax.dynamic_slice(
+            stack, at + (0,) * (stack.ndim - n),
+            (1,) * n + stack.shape[n:]).reshape(stack.shape[n:])
+
+    @staticmethod
+    def _put(stack: jax.Array, new: jax.Array, at: tuple) -> jax.Array:
+        """``new`` written into a stacked cache leaf from the indices
+        ``at``, the leading ones then, for K/V, (batch, position); the
+        rest from 0.  A whole entry, or K/V rows from a position."""
+        new = new.reshape((1,) * (stack.ndim - new.ndim) + new.shape)
+        return jax.lax.dynamic_update_slice(
+            stack, new.astype(stack.dtype), at + (0,) * (stack.ndim - len(at)))
+
+    # ------------------------------------------------------------------ mixers and the second half
+
+    def _qkv(self, bp: dict, h: jax.Array, positions):
         c = self.cfg
         hd = c.hd
         b, s, _ = h.shape
@@ -222,46 +284,137 @@ class LM:
         q = q.reshape(b, s, c.n_heads, hd)
         k = k.reshape(b, s, c.n_kv_heads, hd)
         v = v.reshape(b, s, c.n_kv_heads, hd)
-        if rope and c.rope_style != "none":
+        if c.rope_style != "none":
             q = apply_rope(q, positions, c.rope_theta, c.rope_style)
             k = apply_rope(k, positions, c.rope_theta, c.rope_style)
         return q, k, v
 
-    def _self_attn(self, bp: dict, x: jax.Array, positions, causal: bool,
-                   rope: bool = True) -> jax.Array:
-        b, s, _ = x.shape
-        h = rms_norm(x, bp["ln1"], self.cfg.norm_eps)
-        q, k, v = self._qkv(bp, h, positions, rope)
-        o = blocked_attention(q, k, v, causal=causal)
-        return x + o.reshape(b, s, -1) @ bp["wo"]
-
-    def _cross_attn(self, bp: dict, x: jax.Array, kv_src: jax.Array) -> jax.Array:
+    def _attention(self, bp: dict, h: jax.Array, cache: dict, at: tuple,
+                   positions, length, causal: bool = True):
+        """Self-attention of the normed ``h``, scores scaled by
+        ``attention_multiplier`` (0: 1/sqrt(hd)).  ``length`` None: over
+        the whole sequence, its K/V written from position 0 into the
+        entries at ``at`` of the stacked ``k``, ``v`` that ``cache``
+        holds ({} in training).  Else one position decoded at
+        ``length``: attended with the entries below ``length`` as stored
+        and its own K/V beside them, then its K/V row written at
+        ``length``; a sequence-sharded cache (``sharded_decode``) goes
+        back whole, each shard its own part.  Returns (y, cache)."""
         c = self.cfg
-        hd = c.hd
+        b, s, _ = h.shape
+        q, k, v = self._qkv(bp, h, positions)
+        scale = c.attention_multiplier
+        if length is None:
+            o = blocked_attention(q, k, v, causal=causal, scale=scale)
+            cache = self._put_kv(cache, at + (0, 0), k, v)
+        elif c.sharded_decode:
+            from .sharding import get_batch_axes
+            o, k, v = decode_attention_sharded(
+                q, self._take(cache["k"], at), self._take(cache["v"], at),
+                k, v, length, dp_axes=get_batch_axes(), scale=scale)
+            cache = self._put_kv(cache, at + (0, 0), k, v)
+        else:
+            o = decode_attention(q, self._take(cache["k"], at),
+                                 self._take(cache["v"], at), length,
+                                 scale=scale, k_new=k, v_new=v)
+            cache = self._put_kv(cache, at + (0, length), k, v)
+        return o.reshape(b, s, -1) @ bp["wo"], cache
+
+    def _put_kv(self, cache: dict, at: tuple, k, v) -> dict:
+        if not cache:
+            return cache
+        return {"k": self._put(cache["k"], k, at),
+                "v": self._put(cache["v"], v, at)}
+
+    def _cross(self, cp: dict, x: jax.Array, src, cache: dict, at: tuple,
+               length):
+        """x + cross-attention of x to ``src`` (the encoder's output, the
+        image embeddings), whose K/V are the entries at ``at`` of the
+        stacked ``xk``, ``xv``: written where ``cache`` holds them over
+        a sequence, read when decoding (``length`` set, ``src`` None).
+        Returns (x, cache)."""
+        c = self.cfg
         b, s, _ = x.shape
-        t = kv_src.shape[1]
-        h = rms_norm(x, bp["ln_x"], c.norm_eps)
-        q = (h @ bp["wq"]).reshape(b, s, c.n_heads, hd)
-        k = (kv_src @ bp["wk"]).reshape(b, t, c.n_kv_heads, hd)
-        v = (kv_src @ bp["wv"]).reshape(b, t, c.n_kv_heads, hd)
-        o = blocked_attention(q, k, v, causal=False)
-        return x + o.reshape(b, s, -1) @ bp["wo"]
+        h = rms_norm(x, cp["ln_x"], c.norm_eps)
+        q = (h @ cp["wq"]).reshape(b, s, c.n_heads, c.hd)
+        if length is None:
+            t = src.shape[1]
+            k = (src @ cp["wk"]).reshape(b, t, c.n_kv_heads, c.hd)
+            v = (src @ cp["wv"]).reshape(b, t, c.n_kv_heads, c.hd)
+            o = blocked_attention(q, k, v, causal=False)
+            if cache:
+                cache = {"xk": self._put(cache["xk"], k, at),
+                         "xv": self._put(cache["xv"], v, at)}
+        else:
+            k, v = self._take(cache["xk"], at), self._take(cache["xv"], at)
+            o = decode_attention(q, k, v, k.shape[1])
+        return x + o.reshape(b, s, -1) @ cp["wo"], cache
+
+    def _mamba2(self, bp: dict, h: jax.Array, cache: dict, at: tuple):
+        """Mamba-2 mixer of the normed ``h``, from and into the layer's
+        entries at ``at`` of the stacked ``conv``, ``ssm`` that ``cache``
+        holds ({} in training).  Returns (y, cache)."""
+        s = self.cfg.ssm
+        st = {n: self._take(v, at) for n, v in cache.items()}
+        y, conv, ssm = mamba2_mixer(
+            bp, h, headdim=s.headdim, n_groups=s.n_groups,
+            state_dim=s.state_dim, chunk=s.chunk, eps=self.cfg.norm_eps,
+            conv0=st.get("conv"), ssm0=st.get("ssm"))
+        if cache:
+            cache = {"conv": self._put(cache["conv"], conv, at),
+                     "ssm": self._put(cache["ssm"], ssm, at)}
+        return y, cache
 
     def _mlp(self, bp: dict, x: jax.Array):
+        """The second half of a layer, x + r * f(norm(x)).  Under a
+        layer pattern f is the held experts plus the shared MLP;
+        otherwise the family's MoE (``moe_mlp``, whose load-balancing
+        loss is the aux loss) or its dense SwiGLU or GELU MLP.  r is the
+        residual multiplier (1 but for granite).  Returns (x, aux loss,
+        (token, expert) pairs routed to held experts)."""
         c = self.cfg
         h = rms_norm(x, bp["ln2"], c.norm_eps)
-        if c.moe is not None:
+        aux, routed = jnp.float32(0.0), jnp.zeros((), jnp.int32)
+        if c.layer_types:
+            b, s, d = h.shape
+            y = jnp.zeros_like(h)
+            if c.moe is not None:
+                e, routed = held_expert_mlp(
+                    h.reshape(b * s, d), bp["router"], bp["wg"], bp["wu"],
+                    bp["wd"], c.moe.top_k, c.moe.first_held)
+                y = e.reshape(b, s, d)
+            if c.shared_d_ff:
+                y = y + swiglu(h, bp["shared_wg"], bp["shared_wu"],
+                               bp["shared_wd"])
+        elif c.moe is not None:
             y, aux = moe_mlp(h, bp["router"], bp["wg"], bp["wu"], bp["wd"],
-                             c.moe.top_k,
-                             group_routing=c.moe_group_routing)
-            return x + y, aux
-        if c.mlp_type == "gelu":
+                             c.moe.top_k, group_routing=c.moe_group_routing)
+        elif c.mlp_type == "gelu":
             y = jax.nn.gelu(h @ bp["wu"]) @ bp["wd"]
-            return x + y, jnp.float32(0.0)
-        return x + swiglu(h, bp["wg"], bp["wu"], bp["wd"]), jnp.float32(0.0)
+        else:
+            y = swiglu(h, bp["wg"], bp["wu"], bp["wd"])
+        return x + c.residual_multiplier * y, aux, routed
+
+    def _layer(self, kind: str, bp: dict, x: jax.Array, cache: dict,
+               at: tuple, positions, length, causal: bool = True):
+        """x + r * mixer(norm(x)), then the second half (``_mlp``); the
+        mixer reads and writes its entries at ``at`` of ``cache``.
+        Returns (x, cache, aux loss, routed pairs)."""
+        c = self.cfg
+        h = rms_norm(x, bp["ln1"], c.norm_eps)
+        if kind == "mamba":
+            y, cache = self._mamba2(bp, h, cache, at)
+        else:
+            y, cache = self._attention(bp, h, cache, at, positions, length,
+                                       causal)
+        x, aux, routed = self._mlp(bp, x + c.residual_multiplier * y)
+        return x, cache, aux, routed
 
     def _ssm_block(self, bp: dict, x: jax.Array, h0=None, conv0=None):
-        """Mamba block over a full sequence.  Returns (y, h_fin, conv_fin)."""
+        """Mamba (v1) block from the states ``h0``, ``conv0`` (zeros when
+        None): over the sequence, or, one position with a state in (a
+        decode step), one step of the recurrence.  Returns (x + y,
+        h_fin, conv_fin)."""
         c = self.cfg
         s = c.ssm
         din = s.expand * c.d_model
@@ -276,122 +429,240 @@ class LM:
         B = proj[..., dt_rank:dt_rank + s.state_dim]
         C = proj[..., dt_rank + s.state_dim:]
         A = -jnp.exp(bp["A_log"])
-        y, h_fin = selective_scan(
-            xi, dt, A, B, C, bp["D"], h0=h0,
-            scan_dtype=_dtype(c.ssm_scan_dtype))
+        if x.shape[1] == 1 and h0 is not None:
+            y, h_fin = selective_scan_step(xi[:, 0], dt[:, 0], A, B[:, 0],
+                                           C[:, 0], bp["D"], h0)
+            y = y[:, None]
+        else:
+            y, h_fin = selective_scan(
+                xi, dt, A, B, C, bp["D"], h0=h0,
+                scan_dtype=_dtype(c.ssm_scan_dtype))
         y = y * jax.nn.silu(z)
         return x + y @ bp["out_proj"], h_fin, conv_fin
+
+    # ------------------------------------------------------------------ the stacks
+
+    def _layers(self) -> tuple[str, ...]:
+        """The kind of each layer of the stack that ``_walk`` runs: the
+        ``layer_types`` pattern; ``n_layers`` attention layers for the
+        dense and moe families; none for the families whose stacks are
+        their own (ssm, hybrid, encdec, vlm)."""
+        c = self.cfg
+        if c.layer_types:
+            return c.layer_types
+        return ("attention",) * c.n_layers if c.family in ("dense", "moe") else ()
+
+    def _group(self, kind: str) -> str:
+        """The stacked parameter group of a kind's layers: one group per
+        kind, named by it, for a ``layer_types`` pattern; the one group
+        ``blocks`` of a stack given by its family."""
+        return kind if self.cfg.layer_types else "blocks"
+
+    def _kinds(self) -> dict[str, int]:
+        """Layers of each kind in ``_layers()``, in order of first use."""
+        out: dict[str, int] = {}
+        for t in self._layers():
+            out[t] = out.get(t, 0) + 1
+        return out
+
+    def _runs(self) -> list[tuple[str, int, int]]:
+        """``_layers()`` as runs of one kind: (kind, first, stop), the
+        indices of the run's layers in that kind's stacked group."""
+        runs: list[tuple[str, int, int]] = []
+        seen: dict[str, int] = {}
+        for t in self._layers():
+            i = seen.get(t, 0)
+            seen[t] = i + 1
+            if runs and runs[-1][0] == t:
+                runs[-1] = (t, runs[-1][1], i + 1)
+            else:
+                runs.append((t, i, i + 1))
+        return runs
+
+    def _walk(self, params: dict, x, state: dict, positions, length,
+              remat: bool = False):
+        """The layer pattern over x, one ``lax.scan`` per run of layers of
+        one kind.  A step's weights are its layer's in the kind's stacked
+        group: the scan's input where the run is the whole group, else
+        indexed; its mixer reads and writes its entries of the stacked
+        leaves in ``state`` ({} in training).  Returns (x, state, aux
+        loss, (token, expert) pairs routed to held experts)."""
+        kinds = self._kinds()
+        aux, routed = jnp.float32(0.0), jnp.zeros((), jnp.int32)
+        for kind, first, stop in self._runs():
+            group = params[self._group(kind)]
+
+            def body(carry, blk, kind=kind, group=group):
+                x, st, aux, routed = carry
+                i, bp = blk
+                if bp is None:
+                    bp = jax.tree.map(
+                        lambda t: jax.lax.dynamic_index_in_dim(t, i, 0, False),
+                        group)
+                x, st, a, r = self._layer(kind, bp, x, st, (i,), positions,
+                                          length)
+                return (x, st, aux + a, routed + r), None
+
+            if remat:
+                body = jax.checkpoint(body)
+            whole = stop - first == kinds[kind]
+            (x, st, aux, routed), _ = jax.lax.scan(
+                body, (x, _pick(state, self._KIND_STATE[kind]), aux, routed),
+                (jnp.arange(first, stop), group if whole else None))
+            state = {**state, **st}
+        return x, state, aux, routed
+
+    def _mamba1_stack(self, params: dict, x, state: dict, positions, length,
+                      remat: bool = False):
+        """The ssm and hybrid families: a Mamba (v1) layer a step, its
+        states the entries of ``h``, ``conv``; in ``hybrid``, after every
+        ``shared_attn_every``-th, the one shared attention block, whose
+        K/V of each application are entries of their own."""
+        c = self.cfg
+        every = c.shared_attn_every if c.family == "hybrid" else 0
+
+        def body(carry, blk):
+            x, st = carry
+            bp, i = blk
+            one = {n: self._take(st[n], (i,)) for n in ("h", "conv") if n in st}
+            x, h, conv = self._ssm_block(bp, x, one.get("h"), one.get("conv"))
+            if st:
+                st = {**st, "h": self._put(st["h"], h, (i,)),
+                      "conv": self._put(st["conv"], conv, (i,))}
+            if every:
+                def attend(x, kv):
+                    x, kv, _, _ = self._layer("attention", params["shared_attn"],
+                                              x, kv, (i // every,), positions,
+                                              length)
+                    return x, kv
+                x, kv = jax.lax.cond((i + 1) % every == 0, attend,
+                                     lambda x, kv: (x, kv), x,
+                                     _pick(st, ("k", "v")))
+                st = {**st, **kv}
+            return (x, st), None
+
+        if remat:
+            body = jax.checkpoint(body)
+        (x, state), _ = jax.lax.scan(
+            body, (x, state), (params["blocks"], jnp.arange(c.n_layers)))
+        return x, state, jnp.float32(0.0), jnp.zeros((), jnp.int32)
+
+    def _encdec(self, params: dict, batch, x, state: dict, positions, length,
+                remat: bool = False):
+        """Whisper-style: over a sequence the non-causal encoder of the
+        stubbed frame embeddings first (decoding, its K/V are cached in
+        ``xk``, ``xv``); then the decoder, learned positions added, a
+        layer causal self-attention, cross-attention to the encoder's
+        output and the MLP."""
+        c = self.cfg
+        aux = jnp.float32(0.0)
+        e = None
+        if length is None:
+            e = (batch["frames"].astype(self.cdt)
+                 + params["pos_enc"][None].astype(self.cdt))
+            e_pos = jnp.arange(c.enc_seq)
+
+            def enc_body(carry, bp):
+                e, aux = carry
+                e, _, a, _ = self._layer("attention", bp, e, {}, (), e_pos,
+                                         None, causal=False)
+                return (e, aux + a), None
+            if remat:
+                enc_body = jax.checkpoint(enc_body)
+            (e, aux), _ = jax.lax.scan(enc_body, (e, aux), params["enc_blocks"])
+        x = x + params["pos_dec"][positions][None].astype(self.cdt)
+
+        def dec_body(carry, blk):
+            x, st, aux = carry
+            bp, cp, i = blk
+            h = rms_norm(x, bp["ln1"], c.norm_eps)
+            y, kv = self._attention(bp, h, _pick(st, ("k", "v")), (i,),
+                                    positions, length)
+            x, xkv = self._cross(cp, x + y, e, _pick(st, ("xk", "xv")), (i,),
+                                 length)
+            x, a, _ = self._mlp(bp, x)
+            return (x, {**kv, **xkv}, aux + a), None
+        if remat:
+            dec_body = jax.checkpoint(dec_body)
+        (x, state, aux), _ = jax.lax.scan(
+            dec_body, (x, state, aux),
+            (params["dec_blocks"], params["dec_cross"], jnp.arange(c.n_layers)))
+        return x, state, aux, jnp.zeros((), jnp.int32)
+
+    def _vlm(self, params: dict, batch, x, state: dict, positions, length,
+             remat: bool = False):
+        """Llama-3.2-Vision-style: units of ``cross_attn_every - 1``
+        self-attention layers (K/V entries at (unit, layer)) and one
+        cross-attention layer to the stubbed image embeddings (decoding,
+        their K/V are cached in ``xk``, ``xv``)."""
+        c = self.cfg
+        img = None if length is not None else batch["img_embeds"].astype(self.cdt)
+        units = c.n_layers // c.cross_attn_every
+
+        def unit(carry, blk):
+            x, st, aux = carry
+            sp, cp, u = blk
+
+            def self_body(carry, blk):
+                x, kv, aux = carry
+                bp, j = blk
+                x, kv, a, _ = self._layer("attention", bp, x, kv, (u, j),
+                                          positions, length)
+                return (x, kv, aux + a), None
+            (x, kv, aux), _ = jax.lax.scan(
+                self_body, (x, _pick(st, ("k", "v")), aux),
+                (sp, jnp.arange(c.cross_attn_every - 1)))
+            x, xkv = self._cross(cp, x, img, _pick(st, ("xk", "xv")), (u,),
+                                 length)
+            x, a, _ = self._mlp(cp, x)
+            return (x, {**kv, **xkv}, aux + a), None
+        if remat:
+            unit = jax.checkpoint(unit)
+        (x, state, aux), _ = jax.lax.scan(
+            unit, (x, state, jnp.float32(0.0)),
+            (params["blocks"], params["cross_blocks"], jnp.arange(units)))
+        return x, state, aux, jnp.zeros((), jnp.int32)
+
+    def _stack(self, params: dict, batch, x, state: dict, positions, length,
+               remat: bool = False):
+        """The model's layers over the embedded x: over a sequence
+        (``length`` None) writing each layer's cache entries where
+        ``state`` holds the cache's per-stream leaves ({} in training),
+        or one position decoded at ``length``.  Returns (x, state, aux
+        loss, (token, expert) pairs routed to held experts)."""
+        c = self.cfg
+        if self._layers():
+            return self._walk(params, x, state, positions, length, remat)
+        if c.family in ("ssm", "hybrid"):
+            return self._mamba1_stack(params, x, state, positions, length,
+                                      remat)
+        if c.family == "encdec":
+            return self._encdec(params, batch, x, state, positions, length,
+                                remat)
+        if c.family == "vlm":
+            return self._vlm(params, batch, x, state, positions, length, remat)
+        raise ValueError(c.family)
+
+    def _embed(self, params: dict, tokens) -> jax.Array:
+        return (params["emb"][tokens].astype(self.cdt)
+                * self.cfg.embedding_multiplier)
+
+    def _final_hidden(self, params: dict, x):
+        """The final norm, and the logits' divisor folded into the hidden
+        states (the head is linear), so that ``x @ lm_head`` are logits."""
+        c = self.cfg
+        return rms_norm(x, params["out_norm"], c.norm_eps) / c.logits_scaling
 
     # ------------------------------------------------------------------ forward (train / prefill-style)
 
     def forward(self, params: dict, batch: dict,
                 remat: bool = True) -> tuple[jax.Array, jax.Array]:
         """Returns (final hidden states (B,S,D), aux loss scalar)."""
-        c = self.cfg
         tokens = batch["tokens"]
-        b, s = tokens.shape
-        x = params["emb"][tokens].astype(self.cdt)
-        positions = jnp.arange(s)
-        aux0 = jnp.float32(0.0)
-
-        if c.layer_types:
-            x, _, _ = self._walk(params, x * c.embedding_multiplier, {},
-                                 positions, None, remat=remat)
-            return self._final_hidden(params, x), aux0
-        if c.family in ("dense", "moe"):
-            def body(carry, bp):
-                x, aux = carry
-                x = self._self_attn(bp, x, positions, causal=True)
-                x, a = self._mlp(bp, x)
-                return (x, aux + a), None
-            if remat:
-                body = jax.checkpoint(body)
-            (x, aux), _ = jax.lax.scan(body, (x, aux0), params["blocks"])
-
-        elif c.family == "ssm":
-            def body(carry, bp):
-                x, aux = carry
-                x, _, _ = self._ssm_block(bp, x)
-                return (x, aux), None
-            if remat:
-                body = jax.checkpoint(body)
-            (x, aux), _ = jax.lax.scan(body, (x, aux0), params["blocks"])
-
-        elif c.family == "hybrid":
-            k = c.shared_attn_every
-            shared = params["shared_attn"]
-
-            def body(carry, blk):
-                x, aux = carry
-                bp, idx = blk
-                x, _, _ = self._ssm_block(bp, x)
-                def with_attn(x):
-                    x = self._self_attn(shared, x, positions, causal=True)
-                    x, _ = self._mlp(shared, x)
-                    return x
-                x = jax.lax.cond((idx + 1) % k == 0, with_attn, lambda x: x, x)
-                return (x, aux), None
-            if remat:
-                body = jax.checkpoint(body)
-            (x, aux), _ = jax.lax.scan(
-                body, (x, aux0), (params["blocks"], jnp.arange(c.n_layers)))
-
-        elif c.family == "encdec":
-            frames = batch["frames"].astype(self.cdt)   # stubbed frontend
-            e = frames + params["pos_enc"][None].astype(self.cdt)
-            e_pos = jnp.arange(c.enc_seq)
-
-            def enc_body(carry, bp):
-                e, aux = carry
-                e = self._self_attn(bp, e, e_pos, causal=False, rope=False)
-                e, a = self._mlp(bp, e)
-                return (e, aux + a), None
-            if remat:
-                enc_body = jax.checkpoint(enc_body)
-            (e, aux), _ = jax.lax.scan(enc_body, (e, aux0), params["enc_blocks"])
-
-            x = x + params["pos_dec"][positions][None].astype(self.cdt)
-
-            def dec_body(carry, blk):
-                x, aux = carry
-                bp, cp = blk
-                x = self._self_attn(bp, x, positions, causal=True, rope=False)
-                x = self._cross_attn(cp, x, e)
-                x, a = self._mlp(bp, x)
-                return (x, aux + a), None
-            if remat:
-                dec_body = jax.checkpoint(dec_body)
-            (x, aux), _ = jax.lax.scan(
-                dec_body, (x, aux), (params["dec_blocks"], params["dec_cross"]))
-            aux = aux
-
-        elif c.family == "vlm":
-            img = batch["img_embeds"].astype(self.cdt)  # stubbed frontend
-            kk = c.cross_attn_every
-
-            def unit_body(carry, blk):
-                x, aux = carry
-                sp, cp = blk     # sp leaves: (k-1, ...), cp leaves: (...)
-
-                def self_body(carry2, bp):
-                    x, aux = carry2
-                    x = self._self_attn(bp, x, positions, causal=True)
-                    x, a = self._mlp(bp, x)
-                    return (x, aux + a), None
-                (x, aux), _ = jax.lax.scan(self_body, (x, aux), sp)
-                x = self._cross_attn(cp, x, img)
-                x, a = self._mlp(cp, x)
-                return (x, aux + a), None
-            if remat:
-                unit_body = jax.checkpoint(unit_body)
-            (x, aux), _ = jax.lax.scan(
-                unit_body, (x, aux0),
-                (params["blocks"], params["cross_blocks"]))
-        else:
-            raise ValueError(c.family)
-
-        x = rms_norm(x, params["out_norm"], c.norm_eps)
-        return x, aux
+        x, _, aux, _ = self._stack(params, batch, self._embed(params, tokens),
+                                   {}, jnp.arange(tokens.shape[1]), None,
+                                   remat)
+        return self._final_hidden(params, x), aux
 
     # ------------------------------------------------------------------ loss
 
@@ -404,7 +675,6 @@ class LM:
              loss_chunk: int = 512) -> jax.Array:
         """Causal LM cross-entropy, logits computed in sequence chunks so
         the (B, S, V) tensor is never materialized."""
-        c = self.cfg
         x, aux = self.forward(params, batch, remat=remat)
         targets = batch["labels"]
         head = self.lm_head(params)
@@ -434,35 +704,25 @@ class LM:
     def init_cache(self, batch: int, max_len: int) -> dict:
         c = self.cfg
         hd = c.hd
-        kv = (c.n_layers, batch, max_len, c.n_kv_heads, hd)
         cache: dict = {"len": jnp.zeros((), jnp.int32)}
-        if c.layer_types:
-            return {**cache, **self._pattern_cache(batch, max_len)}
-        if c.family in ("dense", "moe"):
-            cache["k"] = jnp.zeros(kv, self.cdt)
-            cache["v"] = jnp.zeros(kv, self.cdt)
-        elif c.family == "ssm":
+        if self._layers():
+            return {**cache, **self._layer_cache(batch, max_len)}
+        if c.family in ("ssm", "hybrid"):
             s = c.ssm
             din = s.expand * c.d_model
             cache["h"] = jnp.zeros((c.n_layers, batch, din, s.state_dim),
                                    jnp.float32)
             cache["conv"] = jnp.zeros((c.n_layers, batch, s.conv_dim - 1, din),
                                       self.cdt)
-        elif c.family == "hybrid":
-            s = c.ssm
-            din = s.expand * c.d_model
-            n_apps = c.n_layers // c.shared_attn_every
-            cache["h"] = jnp.zeros((c.n_layers, batch, din, s.state_dim),
-                                   jnp.float32)
-            cache["conv"] = jnp.zeros((c.n_layers, batch, s.conv_dim - 1, din),
-                                      self.cdt)
-            cache["k"] = jnp.zeros((n_apps, batch, max_len, c.n_kv_heads, hd),
-                                   self.cdt)
-            cache["v"] = jnp.zeros((n_apps, batch, max_len, c.n_kv_heads, hd),
-                                   self.cdt)
+            if c.family == "hybrid":
+                n_apps = c.n_layers // c.shared_attn_every
+                cache["k"] = jnp.zeros(
+                    (n_apps, batch, max_len, c.n_kv_heads, hd), self.cdt)
+                cache["v"] = jnp.zeros_like(cache["k"])
         elif c.family == "encdec":
-            cache["k"] = jnp.zeros(kv, self.cdt)
-            cache["v"] = jnp.zeros(kv, self.cdt)
+            cache["k"] = jnp.zeros(
+                (c.n_layers, batch, max_len, c.n_kv_heads, hd), self.cdt)
+            cache["v"] = jnp.zeros_like(cache["k"])
             cache["xk"] = jnp.zeros(
                 (c.n_layers, batch, c.enc_seq, c.n_kv_heads, hd), self.cdt)
             cache["xv"] = jnp.zeros_like(cache["xk"])
@@ -477,413 +737,14 @@ class LM:
             cache["xv"] = jnp.zeros_like(cache["xk"])
         return cache
 
-    def _attn_decode(self, bp: dict, x1: jax.Array, kc, vc, length,
-                     rope: bool = True):
-        """One-token self-attention against a cache slice.
-        x1: (B, 1, D); kc/vc: (B, T, Hkv, hd)."""
-        c = self.cfg
-        hd = c.hd
-        b = x1.shape[0]
-        h = rms_norm(x1, bp["ln1"], c.norm_eps)
-        q = h @ bp["wq"]
-        k = h @ bp["wk"]
-        v = h @ bp["wv"]
-        if "bq" in bp:
-            q, k, v = q + bp["bq"], k + bp["bk"], v + bp["bv"]
-        q = q.reshape(b, 1, c.n_heads, hd)
-        k = k.reshape(b, 1, c.n_kv_heads, hd)
-        v = v.reshape(b, 1, c.n_kv_heads, hd)
-        if rope:
-            pos = jnp.full((1,), length, jnp.int32)
-            q = apply_rope(q, pos, c.rope_theta, c.rope_style)
-            k = apply_rope(k, pos, c.rope_theta, c.rope_style)
-        if c.sharded_decode:
-            from .layers import decode_attention_sharded
-            from .sharding import get_batch_axes
-            o, kc, vc = decode_attention_sharded(
-                q, kc, vc, k, v, length, dp_axes=get_batch_axes())
-            return x1 + o.reshape(b, 1, -1) @ bp["wo"], kc, vc
-        kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype),
-                                          (0, length, 0, 0))
-        vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype),
-                                          (0, length, 0, 0))
-        o = decode_attention(q, kc, vc, length + 1)
-        return x1 + o.reshape(b, 1, -1) @ bp["wo"], kc, vc
-
-    def _cross_decode(self, bp: dict, x1: jax.Array, xk, xv):
-        c = self.cfg
-        b = x1.shape[0]
-        h = rms_norm(x1, bp["ln_x"], c.norm_eps)
-        q = (h @ bp["wq"]).reshape(b, 1, c.n_heads, c.hd)
-        o = decode_attention(q, xk, xv, xk.shape[1])
-        return x1 + o.reshape(b, 1, -1) @ bp["wo"]
-
-    def decode_step(self, params: dict, cache: dict,
-                    token: jax.Array) -> tuple[dict, jax.Array]:
-        """token: (B,) int32 -> (new_cache, logits (B, V))."""
-        c = self.cfg
-        b = token.shape[0]
-        length = cache["len"]
-        x = params["emb"][token][:, None].astype(self.cdt)   # (B, 1, D)
-
-        if c.layer_types:
-            return self._pattern_decode(params, cache, x)
-        if c.family in ("dense", "moe"):
-            # K and V ride in the carry, so that a donated cache is
-            # updated in place (as the scan's outputs they would fill a
-            # new buffer on every step), and only the token's position
-            # is written back: the whole layer slice would be written
-            # otherwise.  A sequence-sharded slice goes back whole, each
-            # shard its own part.
-            def put(stack, new, i):
-                if c.sharded_decode:
-                    return jax.lax.dynamic_update_index_in_dim(stack, new, i, 0)
-                row = jax.lax.dynamic_slice_in_dim(new, length, 1, 1)
-                return jax.lax.dynamic_update_slice(stack, row[None],
-                                                    (i, 0, length, 0, 0))
-
-            def body(carry, blk):
-                x, ks, vs = carry
-                bp, i = blk
-                kc = jax.lax.dynamic_index_in_dim(ks, i, 0, False)
-                vc = jax.lax.dynamic_index_in_dim(vs, i, 0, False)
-                x, kc, vc = self._attn_decode(bp, x, kc, vc, length)
-                x, _ = self._mlp(bp, x)
-                return (x, put(ks, kc, i), put(vs, vc, i)), None
-            (x, ks, vs), _ = jax.lax.scan(
-                body, (x, cache["k"], cache["v"]),
-                (params["blocks"], jnp.arange(c.n_layers)))
-            cache = {**cache, "k": ks, "v": vs}
-
-        elif c.family == "ssm":
-            s = c.ssm
-            din = s.expand * c.d_model
-
-            def body(x, blk):
-                bp, h0, conv0 = blk
-                xin = x
-                hh = rms_norm(x, bp["ln"], c.norm_eps)
-                xz = hh @ bp["in_proj"]
-                xi, z = xz[..., :din], xz[..., din:]
-                xi, conv_new = causal_conv1d(xi, bp["conv_w"], conv0)
-                xi = jax.nn.silu(xi)[:, 0]
-                dt_rank = bp["dt_proj"].shape[-2]
-                proj = xi @ bp["x_proj"]
-                dt = proj[..., :dt_rank] @ bp["dt_proj"] + bp["dt_bias"]
-                B = proj[..., dt_rank:dt_rank + s.state_dim]
-                C = proj[..., dt_rank + s.state_dim:]
-                A = -jnp.exp(bp["A_log"])
-                y, h_new = selective_scan_step(xi, dt, A, B, C, bp["D"], h0)
-                y = y[:, None] * jax.nn.silu(z)
-                return xin + y @ bp["out_proj"], (h_new, conv_new)
-            x, (hs, convs) = jax.lax.scan(
-                body, x, (params["blocks"], cache["h"], cache["conv"]))
-            cache = {**cache, "h": hs, "conv": convs}
-
-        elif c.family == "hybrid":
-            s = c.ssm
-            din = s.expand * c.d_model
-            k_every = c.shared_attn_every
-            shared = params["shared_attn"]
-
-            def body(carry, blk):
-                x, kall, vall = carry
-                bp, h0, conv0, idx = blk
-                xin = x
-                hh = rms_norm(x, bp["ln"], c.norm_eps)
-                xz = hh @ bp["in_proj"]
-                xi, z = xz[..., :din], xz[..., din:]
-                xi, conv_new = causal_conv1d(xi, bp["conv_w"], conv0)
-                xi = jax.nn.silu(xi)[:, 0]
-                dt_rank = bp["dt_proj"].shape[-2]
-                proj = xi @ bp["x_proj"]
-                dt = proj[..., :dt_rank] @ bp["dt_proj"] + bp["dt_bias"]
-                B = proj[..., dt_rank:dt_rank + s.state_dim]
-                C = proj[..., dt_rank + s.state_dim:]
-                A = -jnp.exp(bp["A_log"])
-                y, h_new = selective_scan_step(xi, dt, A, B, C, bp["D"], h0)
-                x = xin + (y[:, None] * jax.nn.silu(z)) @ bp["out_proj"]
-
-                app = idx // k_every
-
-                def with_attn(ops):
-                    x, kall, vall = ops
-                    kc = jax.lax.dynamic_index_in_dim(kall, app, 0, False)
-                    vc = jax.lax.dynamic_index_in_dim(vall, app, 0, False)
-                    x, kc, vc = self._attn_decode(shared, x, kc, vc, length)
-                    x, _ = self._mlp(shared, x)
-                    kall = jax.lax.dynamic_update_index_in_dim(kall, kc, app, 0)
-                    vall = jax.lax.dynamic_update_index_in_dim(vall, vc, app, 0)
-                    return x, kall, vall
-                x, kall, vall = jax.lax.cond(
-                    (idx + 1) % k_every == 0, with_attn, lambda o: o,
-                    (x, kall, vall))
-                return (x, kall, vall), (h_new, conv_new)
-            (x, kall, vall), (hs, convs) = jax.lax.scan(
-                body, (x, cache["k"], cache["v"]),
-                (params["blocks"], cache["h"], cache["conv"],
-                 jnp.arange(c.n_layers)))
-            cache = {**cache, "h": hs, "conv": convs, "k": kall, "v": vall}
-
-        elif c.family == "encdec":
-            x = x + params["pos_dec"][length][None, None].astype(self.cdt)
-
-            def body(x, blk):
-                bp, cp, kc, vc, xk, xv = blk
-                x, kc, vc = self._attn_decode(bp, x, kc, vc, length, rope=False)
-                x = self._cross_decode(cp, x, xk, xv)
-                x, _ = self._mlp(bp, x)
-                return x, (kc, vc)
-            x, (ks, vs) = jax.lax.scan(
-                body, x, (params["dec_blocks"], params["dec_cross"],
-                          cache["k"], cache["v"], cache["xk"], cache["xv"]))
-            cache = {**cache, "k": ks, "v": vs}
-
-        elif c.family == "vlm":
-            def unit(x, blk):
-                sp, cp, kc, vc, xk, xv = blk   # kc: (k-1, B, T, Hkv, hd)
-
-                def self_body(x, sblk):
-                    bp, kc1, vc1 = sblk
-                    x, kc1, vc1 = self._attn_decode(bp, x, kc1, vc1, length)
-                    x, _ = self._mlp(bp, x)
-                    return x, (kc1, vc1)
-                x, (kc, vc) = jax.lax.scan(self_body, x, (sp, kc, vc))
-                x = self._cross_decode(cp, x, xk, xv)
-                x, _ = self._mlp(cp, x)
-                return x, (kc, vc)
-            x, (ks, vs) = jax.lax.scan(
-                unit, x, (params["blocks"], params["cross_blocks"],
-                          cache["k"], cache["v"], cache["xk"], cache["xv"]))
-            cache = {**cache, "k": ks, "v": vs}
-        else:
-            raise ValueError(c.family)
-
-        x = rms_norm(x, params["out_norm"], c.norm_eps)
-        logits = (x[:, 0] @ self.lm_head(params)).astype(jnp.float32)
-        cache["len"] = length + 1
-        return cache, logits
-
-    # ------------------------------------------------------------------ prefill
-
-    def prefill(self, params: dict, batch: dict, max_len: int) -> tuple[dict, jax.Array]:
-        """Run the full prompt, build the decode cache, return last logits.
-
-        For dense families the per-layer K/V from the forward pass are
-        recomputed here layer-by-layer (scan) into the cache.
-        """
-        c = self.cfg
-        tokens = batch["tokens"]
-        b, s = tokens.shape
-        cache = self.init_cache(b, max_len)
-        positions = jnp.arange(s)
-        x = params["emb"][tokens].astype(self.cdt)
-
-        if c.layer_types:
-            x, state, _ = self._walk(params, x * c.embedding_multiplier,
-                                     self._state(cache), positions, None)
-            x = self._final_hidden(params, x[:, -1:])
-            logits = (x[:, 0] @ self.lm_head(params)).astype(jnp.float32)
-            return {**cache, **state, "len": jnp.asarray(s, jnp.int32)}, logits
-        if c.family in ("dense", "moe"):
-            def body(x, bp):
-                h = rms_norm(x, bp["ln1"], c.norm_eps)
-                q, k, v = self._qkv(bp, h, positions)
-                o = blocked_attention(q, k, v, causal=True)
-                x = x + o.reshape(b, s, -1) @ bp["wo"]
-                x, _ = self._mlp(bp, x)
-                return x, (k.astype(self.cdt), v.astype(self.cdt))
-            x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
-            cache["k"] = jax.lax.dynamic_update_slice(
-                cache["k"], ks, (0, 0, 0, 0, 0))
-            cache["v"] = jax.lax.dynamic_update_slice(
-                cache["v"], vs, (0, 0, 0, 0, 0))
-        elif c.family == "ssm":
-            def body(x, bp):
-                x, h_fin, conv_fin = self._ssm_block(bp, x)
-                return x, (h_fin, conv_fin.astype(self.cdt))
-            x, (hs, convs) = jax.lax.scan(body, x, params["blocks"])
-            cache["h"], cache["conv"] = hs, convs
-        elif c.family == "hybrid":
-            k_every = c.shared_attn_every
-            shared = params["shared_attn"]
-
-            def body(carry, blk):
-                x, kall, vall = carry
-                bp, idx = blk
-                x, h_fin, conv_fin = self._ssm_block(bp, x)
-
-                def with_attn(ops):
-                    x, kall, vall = ops
-                    app = idx // k_every
-                    h = rms_norm(x, shared["ln1"], c.norm_eps)
-                    q, kk, vv = self._qkv(shared, h, positions)
-                    o = blocked_attention(q, kk, vv, causal=True)
-                    x = x + o.reshape(b, s, -1) @ shared["wo"]
-                    x, _ = self._mlp(shared, x)
-                    pad = kall.shape[2] - s
-                    kk = jnp.pad(kk.astype(self.cdt),
-                                 ((0, 0), (0, pad), (0, 0), (0, 0)))
-                    vv = jnp.pad(vv.astype(self.cdt),
-                                 ((0, 0), (0, pad), (0, 0), (0, 0)))
-                    kall = jax.lax.dynamic_update_index_in_dim(kall, kk, app, 0)
-                    vall = jax.lax.dynamic_update_index_in_dim(vall, vv, app, 0)
-                    return x, kall, vall
-                x, kall, vall = jax.lax.cond(
-                    (idx + 1) % k_every == 0, with_attn, lambda o: o,
-                    (x, kall, vall))
-                return (x, kall, vall), (h_fin, conv_fin.astype(self.cdt))
-            (x, kall, vall), (hs, convs) = jax.lax.scan(
-                body, (x, cache["k"], cache["v"]),
-                (params["blocks"], jnp.arange(c.n_layers)))
-            cache.update(h=hs, conv=convs, k=kall, v=vall)
-        elif c.family == "encdec":
-            frames = batch["frames"].astype(self.cdt)
-            e = frames + params["pos_enc"][None].astype(self.cdt)
-            e_pos = jnp.arange(c.enc_seq)
-
-            def enc_body(e, bp):
-                e = self._self_attn(bp, e, e_pos, causal=False, rope=False)
-                e, _ = self._mlp(bp, e)
-                return e, None
-            e, _ = jax.lax.scan(enc_body, e, params["enc_blocks"])
-            x = x + params["pos_dec"][positions][None].astype(self.cdt)
-
-            def dec_body(x, blk):
-                bp, cp = blk
-                h = rms_norm(x, bp["ln1"], c.norm_eps)
-                q, k, v = self._qkv(bp, h, positions, rope=False)
-                o = blocked_attention(q, k, v, causal=True)
-                x = x + o.reshape(b, s, -1) @ bp["wo"]
-                x = self._cross_attn(cp, x, e)
-                xk = (e @ cp["wk"]).reshape(b, -1, c.n_kv_heads, c.hd)
-                xv = (e @ cp["wv"]).reshape(b, -1, c.n_kv_heads, c.hd)
-                x, _ = self._mlp(bp, x)
-                return x, (k.astype(self.cdt), v.astype(self.cdt),
-                           xk.astype(self.cdt), xv.astype(self.cdt))
-            x, (ks, vs, xks, xvs) = jax.lax.scan(
-                dec_body, x, (params["dec_blocks"], params["dec_cross"]))
-            cache["k"] = jax.lax.dynamic_update_slice(
-                cache["k"], ks, (0, 0, 0, 0, 0))
-            cache["v"] = jax.lax.dynamic_update_slice(
-                cache["v"], vs, (0, 0, 0, 0, 0))
-            cache["xk"], cache["xv"] = xks, xvs
-        elif c.family == "vlm":
-            img = batch["img_embeds"].astype(self.cdt)
-
-            def unit(x, blk):
-                sp, cp = blk
-
-                def self_body(x, bp):
-                    h = rms_norm(x, bp["ln1"], c.norm_eps)
-                    q, k, v = self._qkv(bp, h, positions)
-                    o = blocked_attention(q, k, v, causal=True)
-                    x = x + o.reshape(b, s, -1) @ bp["wo"]
-                    x, _ = self._mlp(bp, x)
-                    return x, (k.astype(self.cdt), v.astype(self.cdt))
-                x, (ks, vs) = jax.lax.scan(self_body, x, sp)
-                x = self._cross_attn(cp, x, img)
-                xk = (img @ cp["wk"]).reshape(b, -1, c.n_kv_heads, c.hd)
-                xv = (img @ cp["wv"]).reshape(b, -1, c.n_kv_heads, c.hd)
-                x, _ = self._mlp(cp, x)
-                return x, (ks, vs, xk.astype(self.cdt), xv.astype(self.cdt))
-            x, (ks, vs, xks, xvs) = jax.lax.scan(
-                unit, x, (params["blocks"], params["cross_blocks"]))
-            cache["k"] = jax.lax.dynamic_update_slice(
-                cache["k"], ks, (0, 0, 0, 0, 0, 0))
-            cache["v"] = jax.lax.dynamic_update_slice(
-                cache["v"], vs, (0, 0, 0, 0, 0, 0))
-            cache["xk"], cache["xv"] = xks, xvs
-        else:
-            raise ValueError(c.family)
-
-        x = rms_norm(x, params["out_norm"], c.norm_eps)
-        logits = (x[:, -1] @ self.lm_head(params)).astype(jnp.float32)
-        cache["len"] = jnp.asarray(s, jnp.int32)
-        return cache, logits
-
-    def cache_batch_axes(self) -> dict:
-        """The batch axis of each leaf of :meth:`init_cache`'s cache, by
-        name: 1, after the leading per-layer axis, except the vision
-        stack's self-attention K/V (units, layers per unit, batch, ...);
-        None for the scalars (the length, counters), which hold no
-        stream's state."""
-        c = self.cfg
-        deep = ("k", "v") if c.family == "vlm" and not c.layer_types else ()
-        shapes = jax.eval_shape(lambda: self.init_cache(1, 1))
-        return {k: None if not v.shape else 2 if k in deep else 1
-                for k, v in shapes.items()}
-
-    # ------------------------------------------------------------------ layer pattern
-
-    def _kinds(self) -> dict[str, int]:
-        """Layers of each kind in ``layer_types``, in order of first use."""
-        out: dict[str, int] = {}
-        for t in self.cfg.layer_types:
-            out[t] = out.get(t, 0) + 1
-        return out
-
-    def _runs(self) -> list[tuple[str, int, int]]:
-        """``layer_types`` as runs of one kind: (kind, first, stop), the
-        indices of the run's layers in that kind's stacked group."""
-        runs: list[tuple[str, int, int]] = []
-        seen: dict[str, int] = {}
-        for t in self.cfg.layer_types:
-            i = seen.get(t, 0)
-            seen[t] = i + 1
-            if runs and runs[-1][0] == t:
-                runs[-1] = (t, runs[-1][1], i + 1)
-            else:
-                runs.append((t, i, i + 1))
-        return runs
-
-    def _pattern_params(self, ini: _Init, kind: str, n: int) -> dict:
-        """One kind's stacked group: its mixer (Mamba-2, or attention as
-        in ``_attn_params``), then ``ln2``, the router over all experts,
-        the held experts (``wg``, ``wu``: (Eh, D, F); ``wd``) and the
-        shared MLP."""
-        c = self.cfg
-        d, lead = c.d_model, (n,)
-        if kind == "mamba":
-            s = c.ssm
-            din = s.expand * d
-            nh = din // s.headdim
-            conv_ch = din + 2 * s.n_groups * s.state_dim
-            p = {"ln1": ini.ones(lead + (d,)),
-                 "in_proj": ini.normal(lead + (d, din + conv_ch + nh)),
-                 "conv_w": ini.normal(lead + (s.conv_dim, conv_ch), scale=0.1),
-                 "dt_bias": ini.zeros(lead + (nh,)),
-                 "A_log": jnp.broadcast_to(
-                     jnp.log(jnp.arange(1, nh + 1, dtype=jnp.float32)),
-                     lead + (nh,)).astype(self.pdt),
-                 "D": ini.ones(lead + (nh,)),
-                 "norm": ini.ones(lead + (din,)),
-                 "out_proj": ini.normal(lead + (din, d))}
-            if s.conv_bias:
-                p["conv_b"] = ini.zeros(lead + (conv_ch,))
-        else:
-            p = self._attn_params(ini, lead)
-        p["ln2"] = ini.ones(lead + (d,))
-        if c.moe is not None:
-            e, f = c.moe.held, c.moe.expert_d_ff
-            p.update(router=ini.normal(lead + (d, c.moe.n_experts)),
-                     wg=ini.normal(lead + (e, d, f)),
-                     wu=ini.normal(lead + (e, d, f)),
-                     wd=ini.normal(lead + (e, f, d)))
-        if c.shared_d_ff:
-            f = c.shared_d_ff
-            p.update(shared_wg=ini.normal(lead + (d, f)),
-                     shared_wu=ini.normal(lead + (d, f)),
-                     shared_wd=ini.normal(lead + (f, d)))
-        return p
-
-    def _pattern_cache(self, batch: int, max_len: int) -> dict:
-        """Per kind: ``conv`` (L, B, K-1, C) and ``ssm`` (L, B, H, P, N),
-        float32, for the mamba layers; ``k``, ``v`` for the attention
-        layers only.  With experts, two int32 counters summed over decode
-        steps: ``expert_routed``, the (token, expert) pairs that landed on
-        a held expert, and ``expert_rows``, the rows the expert matmuls
-        computed (they wrap past 2**31)."""
+    def _layer_cache(self, batch: int, max_len: int) -> dict:
+        """The walked stack's cache: ``conv`` (L, B, K-1, C) and ``ssm``
+        (L, B, H, P, N), float32, for the mamba layers; ``k``, ``v`` (L,
+        B, T, Hkv, hd) for the attention layers.  With held experts, two
+        int32 counters summed over decode steps: ``expert_routed``, the
+        (token, expert) pairs that landed on a held expert, and
+        ``expert_rows``, the rows the expert matmuls computed (they wrap
+        past 2**31)."""
         c = self.cfg
         kinds = self._kinds()
         out: dict = {}
@@ -900,125 +761,55 @@ class LM:
             kv = (kinds["attention"], batch, max_len, c.n_kv_heads, c.hd)
             out["k"] = jnp.zeros(kv, self.cdt)
             out["v"] = jnp.zeros(kv, self.cdt)
-        if c.moe is not None:
+        if c.layer_types and c.moe is not None:
             out["expert_routed"] = jnp.zeros((), jnp.int32)
             out["expert_rows"] = jnp.zeros((), jnp.int32)
         return out
 
-    def _state(self, cache: dict) -> dict:
-        return {k: cache[k] for names in self._KIND_STATE.values()
-                for k in names if k in cache}
+    @staticmethod
+    def _state(cache: dict) -> dict:
+        """The cache's per-stream leaves: all but the scalars (the
+        length, the counters)."""
+        return {k: v for k, v in cache.items() if v.ndim}
 
-    def _pattern_attn(self, bp: dict, h, st: dict, positions, length):
-        """Attention mixer: over the whole sequence (``length`` None;
-        writes K/V from position 0 when ``st`` holds the cache slices), or
-        one position decoded at ``length`` through the grouped
-        ``decode_attention``."""
-        c = self.cfg
-        b, s, _ = h.shape
-        q, k, v = self._qkv(bp, h, positions)
-        if length is None:
-            o = blocked_attention(q, k, v, causal=True,
-                                  scale=c.attention_multiplier)
-            if st:
-                st = {"k": jax.lax.dynamic_update_slice(
-                          st["k"], k.astype(st["k"].dtype), (0, 0, 0, 0)),
-                      "v": jax.lax.dynamic_update_slice(
-                          st["v"], v.astype(st["v"].dtype), (0, 0, 0, 0))}
-        else:
-            kc = jax.lax.dynamic_update_slice(
-                st["k"], k.astype(st["k"].dtype), (0, length, 0, 0))
-            vc = jax.lax.dynamic_update_slice(
-                st["v"], v.astype(st["v"].dtype), (0, length, 0, 0))
-            o = decode_attention(q, kc, vc, length + 1,
-                                 scale=c.attention_multiplier)
-            st = {"k": kc, "v": vc}
-        return o.reshape(b, s, -1) @ bp["wo"], st
-
-    def _pattern_mamba(self, bp: dict, h, st: dict):
-        """Mamba-2 mixer, carrying the layer's conv and ssm state where
-        ``st`` holds them (prefill and decode)."""
-        s = self.cfg.ssm
-        y, conv, ssm = mamba2_mixer(
-            bp, h, headdim=s.headdim, n_groups=s.n_groups,
-            state_dim=s.state_dim, chunk=s.chunk, eps=self.cfg.norm_eps,
-            conv0=st.get("conv"), ssm0=st.get("ssm"))
-        return y, ({"conv": conv, "ssm": ssm} if st else st)
-
-    def _pattern_layer(self, kind: str, bp: dict, x, st: dict, positions,
-                       length):
-        """x + r * mixer(norm(x)), then x + r * (experts + shared MLP)
-        of norm(x), r the residual multiplier.  Returns (x, the layer's
-        cache slices, (token, expert) pairs routed to held experts)."""
-        c = self.cfg
-        h = rms_norm(x, bp["ln1"], c.norm_eps)
-        if kind == "mamba":
-            y, st = self._pattern_mamba(bp, h, st)
-        else:
-            y, st = self._pattern_attn(bp, h, st, positions, length)
-        x = x + c.residual_multiplier * y
-        h = rms_norm(x, bp["ln2"], c.norm_eps)
-        b, s, d = h.shape
-        y = jnp.zeros_like(h)
-        routed = jnp.zeros((), jnp.int32)
-        if c.moe is not None:
-            e, routed = held_expert_mlp(
-                h.reshape(b * s, d), bp["router"], bp["wg"], bp["wu"],
-                bp["wd"], c.moe.top_k, c.moe.first_held)
-            y = e.reshape(b, s, d)
-        if c.shared_d_ff:
-            y = y + swiglu(h, bp["shared_wg"], bp["shared_wu"], bp["shared_wd"])
-        return x + c.residual_multiplier * y, st, routed
-
-    def _walk(self, params: dict, x, state: dict, positions, length,
-              remat: bool = False):
-        """The layer pattern over x, one ``lax.scan`` per run of layers of
-        one kind.  Each step takes its layer's weights, and its slices of
-        the cache leaves in ``state``, at the layer's index in its kind's
-        stacked group, and writes the slices back there.  Returns (x,
-        state, (token, expert) pairs routed to held experts)."""
-        routed = jnp.zeros((), jnp.int32)
-        for kind, first, stop in self._runs():
-            group = params[kind]
-            names = [n for n in self._KIND_STATE[kind] if n in state]
-
-            def body(carry, i, kind=kind, group=group, names=names):
-                x, st, routed = carry
-                bp = jax.tree.map(
-                    lambda t: jax.lax.dynamic_index_in_dim(t, i, 0, False), group)
-                one = {n: jax.lax.dynamic_index_in_dim(st[n], i, 0, False)
-                       for n in names}
-                x, one, r = self._pattern_layer(kind, bp, x, one, positions,
-                                                length)
-                st = {n: jax.lax.dynamic_update_index_in_dim(
-                          st[n], one[n].astype(st[n].dtype), i, 0)
-                      for n in names}
-                return (x, st, routed + r), None
-
-            if remat:
-                body = jax.checkpoint(body)
-            (x, st, routed), _ = jax.lax.scan(
-                body, (x, {n: state[n] for n in names}, routed),
-                jnp.arange(first, stop))
-            state = {**state, **st}
-        return x, state, routed
-
-    def _final_hidden(self, params: dict, x):
-        """The final norm, and the logits' divisor folded into the hidden
-        states (the head is linear), so that ``x @ lm_head`` are logits."""
-        c = self.cfg
-        return rms_norm(x, params["out_norm"], c.norm_eps) / c.logits_scaling
-
-    def _pattern_decode(self, params: dict, cache: dict, x):
+    def decode_step(self, params: dict, cache: dict,
+                    token: jax.Array) -> tuple[dict, jax.Array]:
+        """token: (B,) int32 -> (new_cache, logits (B, V))."""
         c = self.cfg
         length = cache["len"]
-        x, state, routed = self._walk(
-            params, x * c.embedding_multiplier, self._state(cache),
-            jnp.full((1,), length, jnp.int32), length)
+        x, state, _, routed = self._stack(
+            params, None, self._embed(params, token)[:, None],
+            self._state(cache), jnp.full((1,), length, jnp.int32), length)
         cache = {**cache, **state, "len": length + 1}
-        if c.moe is not None:
+        if "expert_rows" in cache:
             cache["expert_routed"] = cache["expert_routed"] + routed
             cache["expert_rows"] = (cache["expert_rows"]
-                                    + x.shape[0] * c.moe.held * c.n_layers)
+                                    + token.shape[0] * c.moe.held * c.n_layers)
         x = self._final_hidden(params, x)
         return cache, (x[:, 0] @ self.lm_head(params)).astype(jnp.float32)
+
+    # ------------------------------------------------------------------ prefill
+
+    def prefill(self, params: dict, batch: dict, max_len: int) -> tuple[dict, jax.Array]:
+        """Run the full prompt, writing each layer's cache entries into a
+        fresh decode cache; return it and the last position's logits."""
+        tokens = batch["tokens"]
+        b, s = tokens.shape
+        cache = self.init_cache(b, max_len)
+        x, state, _, _ = self._stack(params, batch, self._embed(params, tokens),
+                                     self._state(cache), jnp.arange(s), None)
+        x = self._final_hidden(params, x[:, -1:])
+        logits = (x[:, 0] @ self.lm_head(params)).astype(jnp.float32)
+        return {**cache, **state, "len": jnp.asarray(s, jnp.int32)}, logits
+
+    def cache_batch_axes(self) -> dict:
+        """The batch axis of each leaf of :meth:`init_cache`'s cache, by
+        name: 1, after the leading per-layer axis, except the vision
+        stack's self-attention K/V (units, layers per unit, batch, ...);
+        None for the scalars (the length, counters), which hold no
+        stream's state."""
+        c = self.cfg
+        deep = ("k", "v") if c.family == "vlm" and not c.layer_types else ()
+        shapes = jax.eval_shape(lambda: self.init_cache(1, 1))
+        return {k: None if not v.shape else 2 if k in deep else 1
+                for k, v in shapes.items()}

@@ -321,50 +321,77 @@ def test_procs_parked_generator_death_fails_loudly():
         rt.run(_slow_fanout_app)
 
 
-def _rmw_chain_app(ctx, root):
-    oids = ctx.balloc(64, root, 6, label="r")
-    for i, o in enumerate(oids):
-        ctx.spawn(lambda c, oo, v=i: c.write(oo, v + 1), [Out(o)])
-    for o in oids:
-        def rmw(c, oo):
-            import time
-            # long enough that the sniper's kill lands while the body
-            # is still in flight (the torn-write window under test)
-            time.sleep(0.3)
-            c.write(oo, c.read(oo) * 2 + 1)
-        ctx.spawn(rmw, [InOut(o)])
-    yield ctx.wait([InOut(root)])
+def _rmw_chain(hold=None):
+    """Six objects written once, then read-modify-written once each.
+    ``hold`` (a path) names, once the file exists, a worker process's
+    pid: the first RMW body that runs in that process makes its write
+    and then, before the activation commits, creates ``hold + ".held"``
+    and waits there until the process is killed (at most 60 s)."""
+    def app(ctx, root):
+        oids = ctx.balloc(64, root, 6, label="r")
+        for i, o in enumerate(oids):
+            ctx.spawn(lambda c, oo, v=i: c.write(oo, v + 1), [Out(o)])
+        for o in oids:
+            def rmw(c, oo, hold=hold):
+                import os
+                import time
+                c.write(oo, c.read(oo) * 2 + 1)
+                if hold is None:
+                    return
+                deadline = time.time() + 60.0
+                while not os.path.exists(hold) and time.time() < deadline:
+                    time.sleep(0.005)
+                with open(hold) as f:
+                    if int(f.read()) != os.getpid():
+                        return
+                try:
+                    os.close(os.open(hold + ".held", os.O_CREAT | os.O_EXCL))
+                except FileExistsError:
+                    return      # this process's later RMWs
+                time.sleep(60.0)
+            ctx.spawn(rmw, [InOut(o)])
+        yield ctx.wait([InOut(root)])
+    return app
 
 
 def test_procs_snapshot_restores_torn_inflight_task(tmp_path):
     """snapshot_dir= on the real-process backend: the init round's
     commits land, then the child is killed while a read-modify-write
-    activation is in flight — exactly the torn-write window — and its
-    object rolls back to the committed value before the replay, so the
-    RMW applies exactly once."""
+    activation is in flight, its write made and not committed —
+    exactly the torn-write window — and its object rolls back to the
+    committed value before the replay, so the RMW applies exactly once.
+    The kill fires once the body says it is in that window, whatever
+    the load on the machine."""
     import threading
     import time
 
     from repro.core.faults import FaultPlan
 
     sr = SerialRuntime()
-    sr.run(_rmw_chain_app)
+    sr.run(_rmw_chain())
+    hold = tmp_path / "hold"
     rt = Myrmics(n_workers=2, sched_levels=[1], backend="procs",
-                 faults=FaultPlan(snapshot_dir=str(tmp_path)))
+                 faults=FaultPlan(snapshot_dir=str(tmp_path / "snap")))
 
     def sniper():
-        deadline = time.time() + 20.0
+        deadline = time.time() + 60.0
         while time.time() < deadline:
-            # wait for an in-flight task on w1 *after* the init round
-            # has committed (6 init completions), i.e. an RMW body
-            if rt.tasks_done >= 6 and \
-                    rt.worker_agent.last_task_of("w1") is not None:
+            # name w1's process to the RMW bodies once it has started
+            pid = rt.sub.proc_report().get("w1", {}).get("pid")
+            if pid:
+                tmp = tmp_path / "hold.tmp"
+                tmp.write_text(str(pid))
+                os.replace(tmp, hold)
+                break
+            time.sleep(0.005)
+        while time.time() < deadline:
+            if os.path.exists(f"{hold}.held"):
                 rt.kill_worker("w1")
                 return
             time.sleep(0.005)
 
     threading.Thread(target=sniper, daemon=True).start()
-    rep = rt.run(_rmw_chain_app)
+    rep = rt.run(_rmw_chain(str(hold)))
     fs = rep.fault_summary()
     assert fs["workers_killed"] == 1
     assert fs["snapshots_saved"] > 0

@@ -38,13 +38,21 @@ def _call(length_kind: str, q, k, v):
     if length_kind == "per_batch":
         lens = [1, 58, T - 1]
         return decode_attention(q, k, v, jnp.asarray(lens, jnp.int32)), lens
+    if length_kind == "own":
+        # position 97's K/V beside the first 97, the cache holding
+        # other values there
+        n = 97
+        stale = (k.at[:, n].set(100.0), v.at[:, n].set(100.0))
+        return decode_attention(q, *stale, n, k_new=k[:, n:n + 1],
+                                v_new=v[:, n:n + 1]), [n + 1] * B
     assert length_kind == "full"
     return decode_attention(q, k, v, k.shape[1]), [T] * B
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
-@pytest.mark.parametrize("length_kind", ["int", "traced", "per_batch", "full"])
+@pytest.mark.parametrize("length_kind",
+                         ["int", "traced", "per_batch", "full", "own"])
 @pytest.mark.parametrize("hq,hkv", [(8, 8), (14, 2), (32, 2)],
                          ids=["mha8", "gqa14_2", "gqa32_2"])
 def test_decode_attention_matches_reference(hq, hkv, length_kind, dtype):

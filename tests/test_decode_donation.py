@@ -1,7 +1,8 @@
 """The serving engine donates its cache to the decode program: a step
 deletes the cache it was given and writes the new one into its buffers,
-the tokens are those of the undonated program, and the model's counters
-that ``run()`` returns stay readable after later steps.  On the CPU, for
+the tokens are those of the undonated program, the model's counters
+that ``run()`` returns stay readable after later steps, and a step
+writes one K/V row a layer.  On the CPU, for
 a tiny dense model and a tiny granite (Mamba-2, attention and held
 experts)."""
 
@@ -97,6 +98,34 @@ def test_greedy_tokens_equal_the_undonated_program(model):
         np.testing.assert_array_equal(np.asarray(tok_d), np.asarray(tok_k))
     for k in kept:
         np.testing.assert_array_equal(np.asarray(donated[k]), np.asarray(kept[k]))
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it (scan and
+    cond bodies, remat, calls)."""
+    for e in jaxpr.eqns:
+        yield e
+        for p in e.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else (p,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def test_decode_writes_one_kv_row_per_attention_layer(model):
+    """The decode step writes into the stacked K/V only the token's row
+    of a layer, (1, B, 1, Hkv, hd); no whole (B, T, Hkv, hd) layer slice
+    goes back."""
+    cfg, params = model
+    lm = LM(cfg)
+    cache = lm.init_cache(2, MAX_LEN)
+    jaxpr = jax.make_jaxpr(lm.decode_step)(
+        params, cache, jnp.zeros(2, jnp.int32)).jaxpr
+    kv = cache["k"].shape
+    writes = [e.invars[1].aval.shape for e in _eqns(jaxpr)
+              if e.primitive.name == "dynamic_update_slice"
+              and e.invars[0].aval.shape == kv]
+    assert writes == [(1, 2, 1) + kv[3:]] * 2, writes
 
 
 #: the engine's counters that do not depend on the clock or the process

@@ -1,5 +1,8 @@
 """Per-architecture smoke tests (reduced configs) + decode consistency."""
 
+import json
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,14 +13,19 @@ from repro.models.transformer import LM
 
 RNG = jax.random.PRNGKey(0)
 
-# One cheap representative arch stays in the fast tier-1 run; the
-# expensive architectures (vision/MoE/mamba hybrids dominate suite wall
-# time) run under `pytest -m slow`.
+# The smoke, training and microbatch tests keep one cheap representative
+# arch in the fast tier-1 run; the expensive architectures (vision/MoE/
+# mamba hybrids dominate suite wall time) run under `pytest -m slow`.
 FAST_ARCHS = ("qwen2_0_5b",)
 ARCH_PARAMS = [
     a if a in FAST_ARCHS else pytest.param(a, marks=pytest.mark.slow)
     for a in ARCH_IDS
 ]
+
+
+#: the served configurations, by their file under bench/configs
+BENCH_CONFIGS = ("chatglm3-6b", "granite-4.0-h-small")
+PINNED_TREES = Path(__file__).parent / "data" / "model_trees.json"
 
 
 def make_batch(cfg, b=2, s=16, with_labels=True):
@@ -70,7 +78,8 @@ def test_train_step_reduces_loss(arch):
     assert losses[-1] < losses[0], losses
 
 
-@pytest.mark.parametrize("arch", ARCH_PARAMS)
+# every preset: each family's prefill and decode paths, about 3 s a case
+@pytest.mark.parametrize("arch", ARCH_IDS)
 def test_decode_matches_forward(arch):
     """prefill(S) + decode(token S) == forward(S+1) last logits."""
     cfg = get_config(arch).smoke()
@@ -144,3 +153,33 @@ def test_full_configs_param_counts():
     for arch, (lo, hi) in expect.items():
         n = get_config(arch).param_count()
         assert lo < n < hi, (arch, n)
+
+
+def _bench_config(name):
+    from bench import harness
+
+    return harness.model_config(json.loads(
+        (harness.BENCH_DIR / "configs" / f"{name}.json").read_text()))
+
+
+def tree_listing(cfg) -> dict:
+    """Path, shape and dtype of each leaf of ``abstract_params()`` and of
+    ``init_cache(2, 16)``."""
+    lm = LM(cfg)
+
+    def listing(tree):
+        return {jax.tree_util.keystr(path): f"{leaf.dtype}{list(leaf.shape)}"
+                for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]}
+    return {"params": listing(lm.abstract_params()),
+            "cache": listing(jax.eval_shape(lambda: lm.init_cache(2, 16)))}
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS + [f"bench:{n}" for n in BENCH_CONFIGS])
+def test_param_and_cache_trees_are_pinned(arch):
+    """Every preset at smoke size, and both served configurations at
+    full size, keep the parameter and cache trees recorded in
+    ``data/model_trees.json``: what checkpoints, the benchmark's tree
+    check and the serving engine's splice read by name."""
+    cfg = (_bench_config(arch[len("bench:"):]) if arch.startswith("bench:")
+           else get_config(arch).smoke())
+    assert tree_listing(cfg) == json.loads(PINNED_TREES.read_text())[arch]
